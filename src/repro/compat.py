@@ -1,5 +1,6 @@
-"""jax version-compatibility shims shared by every plane (mesh serving in
-``core/``, training substrate in ``launch/``/``train/``, models).
+"""The two mesh spellings shared by every plane (mesh serving in ``core/``,
+training substrate in ``launch/``/``train/``, models), in the installed
+jax's form.
 
 Kept dependency-free (imports only jax) so no plane picks up another
 plane's modules just to spell ``shard_map``.
@@ -11,44 +12,17 @@ import jax
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions (older releases ship it under
-    ``jax.experimental.shard_map`` with ``check_rep`` instead of
-    ``check_vma``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """``jax.shard_map`` without the replication check (the mesh programs
+    return per-device state under ``P(all_axes)`` specs)."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 
 def make_mesh_compat(axis_shapes, axis_names):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` where present,
-    plain ``jax.make_mesh`` without it, raw ``jax.sharding.Mesh`` on releases
-    predating ``jax.make_mesh`` entirely."""
-    if hasattr(jax, "make_mesh"):
-        try:
-            return jax.make_mesh(
-                axis_shapes,
-                axis_names,
-                axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-            )
-        except (AttributeError, TypeError):
-            return jax.make_mesh(axis_shapes, axis_names)
-    import math
-
-    n = math.prod(axis_shapes)
-    devices = jax.devices()
-    if len(devices) < n:
-        raise ValueError(
-            f"mesh {tuple(axis_shapes)} needs {n} devices, "
-            f"have {len(devices)}"
-        )
-    import numpy as np
-
-    return jax.sharding.Mesh(
-        np.asarray(devices[:n]).reshape(axis_shapes), axis_names
+    """``jax.make_mesh`` with every axis ``Auto``-typed."""
+    return jax.make_mesh(
+        axis_shapes,
+        axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
     )

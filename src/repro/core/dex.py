@@ -35,7 +35,8 @@ dataflow for all four ops in core/engine.py.
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple, Tuple
+import math
+from typing import NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +50,7 @@ from repro.core.fleet_cache import (  # noqa: F401  (re-exported compat names)
     init_cache,
 )
 from repro.core.nodes import FANOUT, KEY_MAX
-from repro.core.pool import PoolMeta, SubtreePool, initial_succ
+from repro.core.pool import PoolMeta, SubtreePool, blocks, initial_succ
 
 NODE_ROW_BYTES = FANOUT * 8 * 3  # keys + children + values on the wire
 OFFLOAD_REQ_BYTES = 16
@@ -184,7 +185,9 @@ def init_state(
         miss_ema=jnp.ones((cfg.n_devices, cfg.n_memory, levels), jnp.float32),
         stats=jnp.zeros((cfg.n_devices, N_STATS), jnp.int64),
         versions=jnp.zeros((cfg.n_devices, n_nodes), jnp.int32),
-        occupancy=jnp.sum(pool.pool_keys != KEY_MAX, axis=-1).astype(jnp.int32),
+        occupancy=jnp.sum(
+            blocks(pool.pool_keys != KEY_MAX), axis=-1, dtype=jnp.int32
+        ),
         route_demand=jnp.zeros((cfg.n_devices, cfg.n_route), jnp.int64),
         succ=jnp.broadcast_to(succ0[None, :], (cfg.n_devices, n_nodes)),
         n_alloc=jnp.full((meta.n_subtrees_padded,), base, jnp.int32),
@@ -201,6 +204,22 @@ def init_state(
         rt_sub=jnp.zeros((max(cfg.route_table_slots, 1),), jnp.int32),
         rt_local=jnp.zeros((max(cfg.route_table_slots, 1),), jnp.int32),
         rt_ver=jnp.full((max(cfg.route_table_slots, 1),), -1, jnp.int32),
+    )
+
+
+def make_dex_mesh(devices: "Sequence[jax.Device] | None" = None):
+    """The route x memory mesh (axes ``("data", "model")``, the
+    :class:`DexMeshConfig` defaults) over ``devices`` — all devices present
+    when None.  The route axis takes the largest divisor of the device count
+    not above its square root: 1 device -> 1x1, 4 -> 2x2, 8 -> 2x4.  Build
+    the config from it as ``DexMeshConfig(n_route=mesh.shape["data"],
+    n_memory=mesh.shape["model"])``."""
+    devices = list(jax.devices() if devices is None else devices)
+    n = len(devices)
+    n_route = max(d for d in range(1, math.isqrt(n) + 1) if n % d == 0)
+    return jax.make_mesh(
+        (n_route, n // n_route), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2, devices=devices,
     )
 
 

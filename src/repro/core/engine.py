@@ -139,7 +139,7 @@ from repro.core.dex import (
 )
 from repro.core.fleet_cache import DexCache, cached_fetch_level
 from repro.core.nodes import FANOUT, KEY_MAX
-from repro.core.pool import PoolMeta, SubtreePool, top_walk
+from repro.core.pool import PoolMeta, SubtreePool, node_rows, top_walk
 from repro.core.write import (
     STATUS_MISS,
     STATUS_OK,
@@ -148,7 +148,6 @@ from repro.core.write import (
     _apply_leaf_writes,
 )
 from repro.kernels.leaf_scan import leaf_scan
-from repro.kernels.ops import use_interpret
 from repro.kernels.ref import leaf_scan_ref
 from repro.obs import latency as obs_latency
 
@@ -388,8 +387,6 @@ def make_dex_engine(
     levels = meta.levels_in_subtree
     hops = scan_hops(meta, max_count) if has_scan else 0
     mc = max_count
-    if interpret is None:
-        interpret = use_interpret()
     s_per = meta.n_subtrees_padded // cfg.n_memory
     # per-level node population of one column's subtrees: the fetch side of
     # the group cost model is capped by it (coalesced reads never exceed
@@ -968,17 +965,16 @@ def make_dex_engine(
                 stl = jnp.where(walkf, stf % s_per, 0).astype(jnp.int32)
                 loc = jnp.zeros(kf.shape, jnp.int32)
                 for _ in range(levels - 1):
-                    rows = pool.pool_keys[stl, loc]
+                    rows = node_rows(pool.pool_keys, stl, loc)
                     cnt = jnp.sum(rows <= kf[:, None], axis=-1)
                     slot = jnp.maximum(cnt - 1, 0).astype(jnp.int32)
-                    loc = jnp.take_along_axis(
-                        pool.pool_children[stl, loc], slot[:, None], axis=-1
-                    )[:, 0]
-                o_rows_k = pool.pool_keys[stl, loc]
+                    loc = pool.pool_children[stl, loc * FANOUT + slot]
+                o_rows_k = node_rows(pool.pool_keys, stl, loc)
                 o_eq = o_rows_k == kf[:, None]
                 o_found = jnp.any(o_eq, axis=-1) & walkf
                 o_val = jnp.sum(
-                    jnp.where(o_eq, pool.pool_values[stl, loc], 0), axis=-1
+                    jnp.where(o_eq, node_rows(pool.pool_values, stl, loc), 0),
+                    axis=-1,
                 )
                 if may_offload:
                     gid_eff = meta.node_gid(stf, loc.astype(jnp.int64))

@@ -4,15 +4,25 @@ The paper stores every subtree rooted at level M on a single memory server
 (§3 Index Placement) so offloaded traversals never chase pointers across
 servers.  On a TPU mesh the equivalent is a *blocked* layout:
 
-    pool_keys    : [n_subtrees, subtree_cap, FANOUT]   -- axis 0 sharded over
-    pool_children: [n_subtrees, subtree_cap, FANOUT]      the `model` axis
-    pool_values  : [n_subtrees, subtree_cap, FANOUT]
+    pool_keys    : [n_subtrees, subtree_cap * FANOUT]   -- axis 0 sharded
+    pool_children: [n_subtrees, subtree_cap * FANOUT]      over the `model`
+    pool_values  : [n_subtrees, subtree_cap * FANOUT]      axis
 
 with all levels above M ("top tree") replicated on every chip — these are
 the paper's root-side nodes that are effectively always cached.  Local node
 ids inside a subtree are level-ordered (root = 0) so the offload executor
 (and the Pallas ``subtree_walk`` kernel) can traverse entirely within one
 VMEM-resident block.
+
+**Block rows.**  A subtree block's node rows lie end to end in one
+``subtree_cap * FANOUT`` row: node ``(s, l)`` is the slice ``[l * FANOUT,
+(l + 1) * FANOUT)`` of row ``s``; :func:`node_rows` / :func:`set_node_rows`
+read and write it and :func:`blocks` gives the ``[S, C, FANOUT]`` view.  A
+``[S, C, 64]`` array has no lane-dense TPU layout (64 pads to 128 lanes), so
+XLA lays such a pool out subtree-minor and re-lays the whole of it out for
+every row gather and scatter — about three pool-sized temporaries per
+engine step, which kept a 10^8-key pool off one 16 GB chip.  Block rows are
+a multiple of 128 lanes, and a row gather reads 64 lanes in place.
 
 **Free-list headroom (the on-mesh SMO allocation layer).**  Each block is
 built ``headroom`` fraction larger than the bulk layout needs; the extra
@@ -46,10 +56,10 @@ class SubtreePool(NamedTuple):
     top_keys: jax.Array       # [T, FANOUT] int64
     top_children: jax.Array   # [T, FANOUT] int32; at level M+1 the entries
                               # are *subtree ids* (pool axis-0 indices)
-    # subtree blocks (levels M..0)
-    pool_keys: jax.Array      # [S, C, FANOUT] int64
-    pool_children: jax.Array  # [S, C, FANOUT] int32 (subtree-local ids)
-    pool_values: jax.Array    # [S, C, FANOUT] int64 (leaf payloads)
+    # subtree blocks (levels M..0) as block rows (see module docstring)
+    pool_keys: jax.Array      # [S, C * FANOUT] int64
+    pool_children: jax.Array  # [S, C * FANOUT] int32 (subtree-local ids)
+    pool_values: jax.Array    # [S, C * FANOUT] int64 (leaf payloads)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -242,9 +252,9 @@ def build_pool(
     pool = SubtreePool(
         top_keys=jnp.asarray(TK),
         top_children=jnp.asarray(TC),
-        pool_keys=jnp.asarray(PK),
-        pool_children=jnp.asarray(PC),
-        pool_values=jnp.asarray(PV),
+        pool_keys=jnp.asarray(PK.reshape(S, cap * FANOUT)),
+        pool_children=jnp.asarray(PC.reshape(S, cap * FANOUT)),
+        pool_values=jnp.asarray(PV.reshape(S, cap * FANOUT)),
     )
     meta = PoolMeta(
         level_m=level_m,
@@ -348,7 +358,7 @@ def compress_separators(pool: SubtreePool, meta: PoolMeta) -> SepPlanes:
     """Build the compressed separator planes for every pool row at load
     (host-side; core/smo.py ``refresh_sep_planes`` keeps them correct
     across on-mesh splits without a full rebuild)."""
-    pk = np.asarray(pool.pool_keys)
+    pk = blocks(np.asarray(pool.pool_keys))
     s, c, f = pk.shape
     prefix, nbits, suffix = compress_rows(pk.reshape(s * c, f))
     return SepPlanes(
@@ -394,6 +404,56 @@ def sep_compression_stats(sep: SepPlanes, meta: PoolMeta) -> dict:
 
 
 # ---------------------------------------------------------------------------
+# Block-row access (see the module docstring)
+# ---------------------------------------------------------------------------
+
+
+def blocks(plane):
+    """``[S, C * FANOUT]`` block rows -> the ``[S, C, FANOUT]`` node view
+    (numpy or jax; on a device this is a relayout — host code and one-off
+    passes only)."""
+    return plane.reshape(plane.shape[0], -1, FANOUT)
+
+
+def _row_index(plane, subtree, local):
+    """(block row, first lane) start indices with numpy index semantics:
+    negative ids wrap; the gather clamps, the scatter drops, the rest."""
+    s, cf = plane.shape
+    c = cf // FANOUT
+    st = jnp.asarray(subtree).astype(jnp.int32)
+    lo = jnp.asarray(local).astype(jnp.int32)
+    st = jnp.where(st < 0, st + s, st)
+    lo = jnp.where(lo < 0, lo + c, lo)
+    st, lo = jnp.broadcast_arrays(st, lo)
+    return jnp.stack([st, lo * FANOUT], axis=-1)
+
+
+def node_rows(plane: jax.Array, subtree, local) -> jax.Array:
+    """Rows ``[..., FANOUT]`` of nodes ``(subtree, local)`` of a block-row
+    plane — ``blocks(plane)[subtree, local]`` without a relayout."""
+    idx = _row_index(plane, subtree, local)
+    dn = jax.lax.GatherDimensionNumbers(
+        offset_dims=(idx.ndim - 1,), collapsed_slice_dims=(0,),
+        start_index_map=(0, 1),
+    )
+    return jax.lax.gather(plane, idx, dn, slice_sizes=(1, FANOUT),
+                          mode="clip")
+
+
+def set_node_rows(plane: jax.Array, subtree, local, rows) -> jax.Array:
+    """``plane`` with ``rows [..., FANOUT]`` written at nodes ``(subtree,
+    local)``; nodes outside the plane are dropped, the same contract as
+    ``.at[subtree, local].set(rows, mode="drop")`` on the node view."""
+    idx = _row_index(plane, subtree, local)
+    dn = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(idx.ndim - 1,), inserted_window_dims=(0,),
+        scatter_dims_to_operand_dims=(0, 1),
+    )
+    return jax.lax.scatter(plane, idx, rows.astype(plane.dtype), dn,
+                           mode="drop")
+
+
+# ---------------------------------------------------------------------------
 # Pure-jnp traversal pieces (shared by Plane B and by kernel oracles)
 # ---------------------------------------------------------------------------
 
@@ -419,7 +479,7 @@ def top_walk(pool: SubtreePool, meta: PoolMeta, queries: jax.Array) -> jax.Array
 
 
 def subtree_walk_ref(
-    block_keys: jax.Array,      # [C, FANOUT] one subtree's nodes
+    block_keys: jax.Array,      # [C, FANOUT] (or a [C * FANOUT] block row)
     block_children: jax.Array,  # [C, FANOUT]
     block_values: jax.Array,    # [C, FANOUT]
     queries: jax.Array,         # [B]
@@ -430,6 +490,10 @@ def subtree_walk_ref(
     Pure-jnp oracle for the Pallas ``subtree_walk`` kernel; also the
     offload executor's reference implementation.  Returns (found, values).
     """
+    block_keys, block_children, block_values = (
+        x.reshape(-1, FANOUT) for x in (block_keys, block_children,
+                                        block_values)
+    )
     queries = queries.astype(jnp.int64)
     b = queries.shape[0]
     local = jnp.zeros((b,), jnp.int32)
@@ -452,11 +516,13 @@ def pool_lookup_ref(
     b = queries.shape[0]
     local = jnp.zeros((b,), jnp.int32)
     for _ in range(meta.levels_in_subtree - 1):
-        rows = pool.pool_keys[st, local]
+        rows = node_rows(pool.pool_keys, st, local)
         s = _slot(rows, queries)
-        local = pool.pool_children[st, local, s]
-    leaf_keys = pool.pool_keys[st, local]
+        local = pool.pool_children[st, local * FANOUT + s]
+    leaf_keys = node_rows(pool.pool_keys, st, local)
     eq = leaf_keys == queries[..., None]
     found = jnp.any(eq, axis=-1)
-    vals = jnp.sum(jnp.where(eq, pool.pool_values[st, local], 0), axis=-1)
+    vals = jnp.sum(
+        jnp.where(eq, node_rows(pool.pool_values, st, local), 0), axis=-1
+    )
     return found, vals

@@ -51,7 +51,7 @@ from repro.core import fleet_cache
 from repro.core.dex import N_STATS, STAT_DROPS, STAT_OPS, DexState
 from repro.core.nodes import KEY_MAX, KEY_MIN
 from repro.core.partition import LogicalPartitions
-from repro.core.pool import PoolMeta
+from repro.core.pool import PoolMeta, blocks
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,12 +101,12 @@ def node_key_ranges(
     leaf) is returned — the leaf-direct route-table trainer
     (core/route_table.py) uses it to keep only leaf fence ranges.
     """
-    pk0 = np.asarray(pool_keys[:, :, 0])              # [S, C] first keys
+    pk0 = blocks(np.asarray(pool_keys))[:, :, 0]      # [S, C] first keys
     n_sub, cap = pk0.shape
     lvl_of = np.full((n_sub, cap), -1, np.int32)
     lvl_of[:, 0] = meta.level_m                       # block roots
     if pool_children is not None:
-        pc = np.asarray(pool_children)
+        pc = blocks(np.asarray(pool_children))
         for lvl in range(meta.level_m, 0, -1):
             s_idx, c_idx = np.where(lvl_of == lvl)
             if s_idx.size == 0:

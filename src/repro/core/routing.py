@@ -31,7 +31,7 @@ import numpy as np
 
 from repro.compat import shard_map_compat  # noqa: F401  (re-export)
 from repro.core.nodes import KEY_MAX
-from repro.core.pool import PoolMeta, SubtreePool
+from repro.core.pool import PoolMeta, SubtreePool, node_rows
 
 
 def hash64(x: jax.Array) -> jax.Array:
@@ -313,9 +313,9 @@ def fetch_rows(
     valid = req != KEY_MAX
     st = jnp.where(valid, st, 0)
     lo = jnp.where(valid, lo, 0)
-    rk = pool.pool_keys[st, lo]                            # [n_mem, cap, F]
-    rc = pool.pool_children[st, lo]
-    rv = pool.pool_values[st, lo]
+    rk = node_rows(pool.pool_keys, st, lo)                 # [n_mem, cap, F]
+    rc = node_rows(pool.pool_children, st, lo)
+    rv = node_rows(pool.pool_values, st, lo)
     rk = jnp.where(valid[..., None], rk, KEY_MAX)
     rc = jnp.where(valid[..., None], rc, 0)
     rv = jnp.where(valid[..., None], rv, 0)

@@ -66,7 +66,10 @@ from repro.core.pool import (
     PoolMeta,
     SepPlanes,
     SubtreePool,
+    blocks,
     compress_rows,
+    node_rows,
+    set_node_rows,
     top_walk,
 )
 from repro.core.write import (
@@ -78,7 +81,6 @@ from repro.core.write import (
 )
 from repro.kernels.leaf_split import leaf_split
 from repro.kernels.leaf_write import leaf_write
-from repro.kernels.ops import use_interpret
 from repro.kernels.ref import leaf_split_ref, leaf_write_ref
 
 
@@ -123,8 +125,6 @@ def make_dex_smo(
     """
     levels = meta.levels_in_subtree
     cap_nodes = meta.subtree_cap
-    if interpret is None:
-        interpret = use_interpret()
     SW = FANOUT  # staged inserts per leaf per round
 
     def local_fn(pool, occupancy, n_alloc, versions, succ, stats,
@@ -163,17 +163,17 @@ def make_dex_smo(
         plocals = [jnp.zeros((n,), jnp.int32)]
         local = jnp.zeros((n,), jnp.int32)
         for _ in range(levels - 1):
-            rows = pool.pool_keys[st, local]
+            rows = node_rows(pool.pool_keys, st, local)
             slot = jnp.maximum(
                 jnp.sum(rows <= k[:, None], axis=-1) - 1, 0
             ).astype(jnp.int32)
-            local = pool.pool_children[st, local, slot]
+            local = pool.pool_children[st, local * FANOUT + slot]
             plocals.append(local)
         leaf_lo = plocals[-1]
         gid_leaf = meta.node_gid(stg, leaf_lo)
 
         # --- 3. conflict resolution + existing keys become value updates --
-        row_k0 = pool.pool_keys[st, leaf_lo]
+        row_k0 = node_rows(pool.pool_keys, st, leaf_lo)
         eqk = row_k0 == k[:, None]
         exists = jnp.any(eqk, axis=-1) & live
         uslot = jnp.argmax(eqk, axis=-1).astype(jnp.int32)
@@ -191,7 +191,7 @@ def make_dex_smo(
         winner = jnp.concatenate([diff, jnp.ones((1,), bool)]) & live_s
         upd_w = winner & exists[order]
         ust = jnp.where(upd_w, st_s, s_local)                   # OOB drop
-        new_pv = pool.pool_values.at[ust, lo_s, uslot[order]].set(
+        new_pv = pool.pool_values.at[ust, lo_s * FANOUT + uslot[order]].set(
             v_s, mode="drop"
         )
 
@@ -258,20 +258,20 @@ def make_dex_smo(
         new_alloc = n_alloc.at[alloc_st].add(1, mode="drop")
 
         # --- 6. leaf merge / split (Pallas kernel or oracle) --------------
-        rows_k = pool.pool_keys[seg_st, seg_lo]
-        rows_v = new_pv[seg_st, seg_lo]
+        rows_k = node_rows(pool.pool_keys, seg_st, seg_lo)
+        rows_v = node_rows(new_pv, seg_st, seg_lo)
         splitter = leaf_split if use_kernel else leaf_split_ref
         skw = {"interpret": interpret} if use_kernel else {}
         lk, lv, rk, rv, occ_l, occ_r, sep, _did = splitter(
             rows_k, rows_v, ins_key_st, ins_val_st, **skw
         )
         w_st = jnp.where(apply_seg, seg_st, s_local)
-        out_pk = pool.pool_keys.at[w_st, seg_lo].set(lk, mode="drop")
-        out_pv = new_pv.at[w_st, seg_lo].set(lv, mode="drop")
+        out_pk = set_node_rows(pool.pool_keys, w_st, seg_lo, lk)
+        out_pv = set_node_rows(new_pv, w_st, seg_lo, lv)
         out_occ = occupancy.at[w_st, seg_lo].set(occ_l, mode="drop")
         r_st = jnp.where(can_split, seg_st, s_local)
-        out_pk = out_pk.at[r_st, sib_lo].set(rk, mode="drop")
-        out_pv = out_pv.at[r_st, sib_lo].set(rv, mode="drop")
+        out_pk = set_node_rows(out_pk, r_st, sib_lo, rk)
+        out_pv = set_node_rows(out_pv, r_st, sib_lo, rv)
         out_occ = out_occ.at[r_st, sib_lo].set(occ_r, mode="drop")
         out_pc = pool.pool_children
 
@@ -336,8 +336,8 @@ def make_dex_smo(
             seg2_active = (
                 jnp.zeros((n,), bool).at[seg2_id].max(act2)
             )
-            rows_pk = out_pk[seg2_st, seg2_lo]
-            rows_pc = out_pc[seg2_st, seg2_lo].astype(jnp.int64)
+            rows_pk = node_rows(out_pk, seg2_st, seg2_lo)
+            rows_pc = node_rows(out_pc, seg2_st, seg2_lo).astype(jnp.int64)
             writer = leaf_write if use_kernel else leaf_write_ref
             wkw = {"interpret": interpret} if use_kernel else {}
             no_us = jnp.full((n, SW), -1, jnp.int32)
@@ -346,15 +346,17 @@ def make_dex_smo(
                 rows_pk, rows_pc, no_us, no_uv, ins_k2, ins_v2, **wkw
             )
             w2 = jnp.where(seg2_active, seg2_st, s_local)
-            out_pk = out_pk.at[w2, seg2_lo].set(nk2, mode="drop")
-            out_pc = out_pc.at[w2, seg2_lo].set(
-                nc2.astype(jnp.int32), mode="drop"
-            )
+            out_pk = set_node_rows(out_pk, w2, seg2_lo, nk2)
+            out_pc = set_node_rows(out_pc, w2, seg2_lo, nc2)
             out_occ = out_occ.at[w2, seg2_lo].set(nocc2, mode="drop")
 
         # --- 8. dense inner pass: split full parents toward the root ------
         n_inner_splits = jnp.int64(0)
         if levels >= 3:
+            # whole-block passes work on the [S, C, F] node view
+            out_pk, out_pc, out_pv = (
+                blocks(x) for x in (out_pk, out_pc, out_pv)
+            )
             flagged0 = need_split & ~parent_room & (m_seg > 0)
             f_st = jnp.where(flagged0, seg_st, s_local)
             flag = (
@@ -474,6 +476,9 @@ def make_dex_smo(
                     .at[row_ix, nf_par].set(True, mode="drop")
                 )
             new_alloc = alloc_g
+            out_pk, out_pc, out_pv = (
+                x.reshape(s_local, -1) for x in (out_pk, out_pc, out_pv)
+            )
 
         # --- 9. statuses back to the requesting lanes ---------------------
         outcome_w = jnp.where(
@@ -715,7 +720,7 @@ def refresh_sep_planes(
     cap = meta.subtree_cap
     s_idx = changed // cap
     l_idx = changed % cap
-    pk = np.asarray(state.pool.pool_keys)
+    pk = blocks(np.asarray(state.pool.pool_keys))
     prefix = np.asarray(sep.prefix).copy()
     nbits = np.asarray(sep.nbits).copy()
     suffix = np.asarray(sep.suffix).copy()

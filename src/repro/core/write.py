@@ -70,7 +70,7 @@ from repro.core.dex import (
     init_state,
 )
 from repro.core.nodes import FANOUT, KEY_MAX
-from repro.core.pool import PoolMeta, build_pool
+from repro.core.pool import PoolMeta, build_pool, node_rows, set_node_rows
 from repro.kernels.leaf_write import leaf_write
 from repro.kernels.ref import leaf_write_ref
 
@@ -90,8 +90,8 @@ def _seg_positions(mask: jax.Array, new_seg: jax.Array) -> jax.Array:
 
 
 def _apply_leaf_writes(
-    pool_keys: jax.Array,    # [S_local, C, F] this memory column's shard
-    pool_values: jax.Array,  # [S_local, C, F]
+    pool_keys: jax.Array,    # [S_local, C * F] this memory column's shard
+    pool_values: jax.Array,  # [S_local, C * F]
     occupancy: jax.Array,    # [S_local, C]
     meta: PoolMeta,
     cfg: DexMeshConfig,
@@ -102,7 +102,7 @@ def _apply_leaf_writes(
     allow_insert: jax.Array,  # [N] bool: absent keys may claim a slack slot
     *,
     use_kernel: bool,
-    interpret: bool,
+    interpret: "bool | None",
 ):
     """Apply one flat *mixed* batch of leaf-write requests to the local pool
     shard.  A lane whose key already sits in the leaf becomes an in-place
@@ -125,7 +125,7 @@ def _apply_leaf_writes(
     valid = gid != KEY_MAX
     st = jnp.where(valid, (gid // meta.subtree_cap) % s_per, 0).astype(jnp.int32)
     lo = jnp.where(valid, gid % meta.subtree_cap, 0).astype(jnp.int32)
-    row_k0 = pool_keys[st, lo]                              # [N, F] pre-batch
+    row_k0 = node_rows(pool_keys, st, lo)                   # [N, F] pre-batch
 
     eqk = row_k0 == key[:, None]
     exists = jnp.any(eqk, axis=-1) & valid
@@ -195,8 +195,8 @@ def _apply_leaf_writes(
     )
 
     # ---- the masked scatter + merge itself (Pallas kernel or oracle) ------
-    rows_k = pool_keys[seg_st, seg_lo]
-    rows_v = pool_values[seg_st, seg_lo]
+    rows_k = node_rows(pool_keys, seg_st, seg_lo)
+    rows_v = node_rows(pool_values, seg_st, seg_lo)
     writer = leaf_write if use_kernel else leaf_write_ref
     kw = {"interpret": interpret} if use_kernel else {}
     new_k, new_v, new_occ = writer(
@@ -207,8 +207,8 @@ def _apply_leaf_writes(
         jnp.zeros((n,), bool).at[seg_id].max(upd_apply | ins_apply)
     )
     w_st = jnp.where(seg_active, seg_st, pool_keys.shape[0])  # OOB drop
-    out_pk = pool_keys.at[w_st, seg_lo].set(new_k, mode="drop")
-    out_pv = pool_values.at[w_st, seg_lo].set(new_v, mode="drop")
+    out_pk = set_node_rows(pool_keys, w_st, seg_lo, new_k)
+    out_pv = set_node_rows(pool_values, w_st, seg_lo, new_v)
     out_occ = occupancy.at[w_st, seg_lo].set(new_occ, mode="drop")
 
     # ---- per-lane status: every lane inherits its (gid, key) winner's fate
@@ -224,7 +224,7 @@ def _apply_leaf_writes(
     status_s = jnp.where(live_s, run_out[run_id], STATUS_MISS)
     status = jnp.zeros((n,), jnp.int32).at[order].set(status_s)
 
-    rows_v_out = out_pv[st, lo]                             # post-batch rows
+    rows_v_out = node_rows(out_pv, st, lo)                  # post-batch rows
     # per-lane: did the lane's target leaf take any fresh insert this batch?
     seg_ins = jnp.zeros((n,), bool).at[seg_id].max(ins_apply)
     ins_lane_s = jnp.where(live_s, seg_ins[seg_id], False)

@@ -1,3 +1,13 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the index plane (and the dormant LM plane).
+
+Every kernel takes ``interpret=None`` by default, resolved by
+:func:`use_interpret`: compiled on a TPU backend, the Pallas interpreter
+anywhere else.  Oracles live in ``ref.py`` with identical signatures."""
+
+import jax
+
+
+def use_interpret() -> bool:
+    """Kernels execute their Python bodies (interpret mode) unless a real
+    TPU backend is present."""
+    return jax.default_backend() != "tpu"

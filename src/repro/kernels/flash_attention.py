@@ -18,6 +18,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import use_interpret
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
@@ -84,7 +86,7 @@ def flash_attention(
     scale: float | None = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: "bool | None" = None,
 ):
     b, h, sq, d = q.shape
     _, hkv, sk, _ = k.shape
@@ -122,6 +124,6 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=use_interpret() if interpret is None else interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d)

@@ -13,13 +13,15 @@ kernel performs
 Because leaves are consecutive in key order, the surviving keys are already
 sorted in window-slot order, so the gather is rank-based: element with
 selection rank ``j`` lands in output column ``j``.  On TPU the rank is a
-lane-wise ``cumsum`` and the gather a one-hot compare+reduce over the window
-— branchless VPU work, no scatter (DESIGN.md §3).
+0/1 triangular matmul over the row (Mosaic lowers no ``cumsum``) and the
+gather a one-hot compare+reduce over the window — branchless VPU work, no
+scatter (DESIGN.md §3).  Per-lane scalars travel as [B, 1] columns so every
+block obeys the TPU's (8, 128) tiling.
 
 int64 keys/values travel as (hi, lo) int32 planes like kernels/node_search.py
 (the TPU VPU has no native 64-bit lanes).  The pure-jnp oracle is
-``kernels/ref.py::leaf_scan_ref``; ``interpret=True`` (the default off-TPU)
-runs the same body through the Pallas interpreter.
+``kernels/ref.py::leaf_scan_ref``; ``interpret=None`` resolves to the
+compiled kernel on a TPU and the Pallas interpreter elsewhere.
 """
 
 from __future__ import annotations
@@ -32,29 +34,21 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.nodes import KEY_MAX
+from repro.kernels import use_interpret
+from repro.kernels.leaf_write import (
+    _KMAX_HI,
+    _KMAX_LO,
+    _ZERO,
+    _count,
+    _exclusive_prefix_count,
+    _join_i64,
+    _live,
+    _lt_planes,
+    _row_block,
+    _split_i64,
+)
 
 BLOCK_B = 8
-
-# KEY_MAX = 0x7FFF_FFFF_FFFF_FFFF as (hi, lo-reinterpreted-signed) planes
-_KMAX_HI = np.int32(0x7FFFFFFF)
-_KMAX_LO = np.int32(-1)
-
-
-def _split_i64(x: jax.Array):
-    """int64 -> (hi int32, lo uint32-as-int32) planes."""
-    hi = (x >> 32).astype(jnp.int32)
-    lo = (x & jnp.int64(0xFFFFFFFF)).astype(jnp.uint32).astype(jnp.int32)
-    return hi, lo
-
-
-def _join_i64(hi: jax.Array, lo: jax.Array) -> jax.Array:
-    return (hi.astype(jnp.int64) << 32) | lo.astype(jnp.uint32).astype(jnp.int64)
-
-
-def _geq_planes(khi, klo, qhi, qlo):
-    """(khi,klo) >= (qhi,qlo) treating lo as unsigned."""
-    flip = jnp.int32(-0x80000000)
-    return (khi > qhi) | ((khi == qhi) & ((klo ^ flip) >= (qlo ^ flip)))
 
 
 def _make_kernel(max_count: int):
@@ -64,39 +58,42 @@ def _make_kernel(max_count: int):
     ):
         khi = khi_ref[...]                     # [B, W] int32
         klo = klo_ref[...]
-        shi = shi_ref[...]                     # [B] int32
+        shi = shi_ref[...]                     # [B, 1] int32
         slo = slo_ref[...]
-        cnt = cnt_ref[...]                     # [B] int32
+        cnt = cnt_ref[...]                     # [B, 1] int32
 
         # 1. in-leaf lower bound, vectorized over the whole window: drop
         #    KEY_MAX padding and keys below the start key
-        valid = ~((khi == _KMAX_HI) & (klo == _KMAX_LO))
-        geq = _geq_planes(khi, klo, shi[:, None], slo[:, None])
-        mask = valid & geq
-        rank = jnp.cumsum(mask.astype(jnp.int32), axis=-1,
-                          dtype=jnp.int32)                   # [B, W]
-        sel = mask & (rank <= cnt[:, None])
-        taken_ref[...] = jnp.sum(sel, axis=-1, dtype=jnp.int32)
+        mask = _live(khi, klo) & ~_lt_planes(khi, klo, shi, slo)
+        rank = _exclusive_prefix_count(mask) + mask.astype(jnp.int32)
+        sel = mask & (rank <= cnt)                           # [B, W]
+        taken = _count(sel, axis=-1, keepdims=True)          # [B, 1]
+        taken_ref[...] = taken
 
         # 2. rank-based masked gather: window element with selection rank
-        #    j+1 -> output column j (one-hot compare + reduce, no scatter)
-        srank = jnp.where(sel, rank, 0)                      # [B, W]
+        #    j+1 -> output column j (one-hot compare + reduce, no scatter);
+        #    ranks 1..taken are all present, so column j is filled iff
+        #    j < taken
+        srank = jnp.where(sel, rank, _ZERO)                  # [B, W]
         jcol = jax.lax.broadcasted_iota(
             jnp.int32, (1, max_count, 1), 1
-        ) + 1                                                # [1, MC, 1]
+        ) + np.int32(1)                                       # [1, MC, 1]
         pick = srank[:, None, :] == jcol                     # [B, MC, W]
-        hit = jnp.any(pick, axis=-1)                         # [B, MC]
+        hit = (
+            jax.lax.broadcasted_iota(jnp.int32, (1, max_count), 1) < taken
+        )                                                    # [B, MC]
 
         def compact(plane, fill):
             got = jnp.sum(
-                jnp.where(pick, plane[:, None, :], 0), axis=-1, dtype=jnp.int32
+                jnp.where(pick, plane[:, None, :], _ZERO), axis=-1,
+                dtype=jnp.int32,
             )
             return jnp.where(hit, got, fill)
 
         okhi_ref[...] = compact(khi, _KMAX_HI)
         oklo_ref[...] = compact(klo, _KMAX_LO)
-        ovhi_ref[...] = compact(vhi_ref[...], 0)
-        ovlo_ref[...] = compact(vlo_ref[...], 0)
+        ovhi_ref[...] = compact(vhi_ref[...], _ZERO)
+        ovlo_ref[...] = compact(vlo_ref[...], _ZERO)
 
     return kernel
 
@@ -111,7 +108,7 @@ def leaf_scan(
     counts: jax.Array,         # [B] int32/int64
     *,
     max_count: int,
-    interpret: bool = True,
+    interpret: "bool | None" = None,
     block_b: int = BLOCK_B,
 ):
     """Compact up to ``counts[b]`` records with key >= ``start_keys[b]`` out
@@ -133,9 +130,9 @@ def leaf_scan(
     shi, slo = _split_i64(start_keys.astype(jnp.int64))
 
     grid = (bp // block_b,)
-    row = pl.BlockSpec((block_b, w), lambda i: (i, 0))
-    out_row = pl.BlockSpec((block_b, max_count), lambda i: (i, 0))
-    lane = pl.BlockSpec((block_b,), lambda i: (i,))
+    row = pl.BlockSpec((block_b, w), _row_block)
+    out_row = pl.BlockSpec((block_b, max_count), _row_block)
+    lane = pl.BlockSpec((block_b, 1), _row_block)
     okhi, oklo, ovhi, ovlo, taken = pl.pallas_call(
         _make_kernel(max_count),
         grid=grid,
@@ -146,10 +143,10 @@ def leaf_scan(
             jax.ShapeDtypeStruct((bp, max_count), jnp.int32),
             jax.ShapeDtypeStruct((bp, max_count), jnp.int32),
             jax.ShapeDtypeStruct((bp, max_count), jnp.int32),
-            jax.ShapeDtypeStruct((bp,), jnp.int32),
+            jax.ShapeDtypeStruct((bp, 1), jnp.int32),
         ],
-        interpret=interpret,
-    )(khi, klo, vhi, vlo, shi, slo, counts)
+        interpret=use_interpret() if interpret is None else interpret,
+    )(khi, klo, vhi, vlo, shi[:, None], slo[:, None], counts[:, None])
     out_k = _join_i64(okhi, oklo)
     out_v = _join_i64(ovhi, ovlo)
-    return out_k[:b], out_v[:b], taken[:b]
+    return out_k[:b], out_v[:b], taken[:b, 0]

@@ -26,8 +26,8 @@ and one split always absorbs the whole batch.
 
 int64 keys/values travel as (hi, lo) int32 planes (the TPU VPU has no
 native 64-bit lanes).  The pure-jnp oracle is
-``kernels/ref.py::leaf_split_ref``; ``interpret=True`` (the default off-TPU)
-runs the same body through the Pallas interpreter.
+``kernels/ref.py::leaf_split_ref``; ``interpret=None`` resolves to the
+compiled kernel on a TPU and the Pallas interpreter elsewhere.
 """
 
 from __future__ import annotations
@@ -36,14 +36,22 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.nodes import KEY_MAX
+from repro.kernels import use_interpret
 from repro.kernels.leaf_write import (
     _KMAX_HI,
     _KMAX_LO,
+    _ZERO,
+    _any,
+    _count,
+    _exclusive_prefix_count,
     _join_i64,
+    _live,
     _lt_planes,
+    _row_block,
     _split_i64,
 )
 
@@ -64,39 +72,41 @@ def _make_kernel(fanout: int):
         ikl = ikl_ref[...]
 
         col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, fanout), 2)
+        # bool vectors cannot be reshaped on Mosaic: 3-D masks come from
+        # int32 planes already broadcast to their position
+        ikh3, ikl3 = ikh[:, :, None], ikl[:, :, None]             # [B, S, 1]
+        khr, klr = khi[:, None, :], klo[:, None, :]               # [B, 1, F]
 
         # merged rank of every element (same branchless pairwise compares as
         # kernels/leaf_write.py: actives are distinct, KEY_MAX never counts)
-        act = ~((ikh == _KMAX_HI) & (ikl == _KMAX_LO))            # [B, S]
-        validr = ~((khi == _KMAX_HI) & (klo == _KMAX_LO))         # [B, F]
-        ins_below_row = act[:, :, None] & _lt_planes(
-            ikh[:, :, None], ikl[:, :, None], khi[:, None, :], klo[:, None, :]
+        act = _live(ikh, ikl)                                     # [B, S]
+        validr = _live(khi, klo)                                  # [B, F]
+        validr3 = _live(khr, klr)                                 # [B, 1, F]
+        act3 = _live(ikh[:, None, :], ikl[:, None, :])            # [B, 1, S]
+        ins_below_row = _live(ikh3, ikl3) & _lt_planes(
+            ikh3, ikl3, khr, klr
         )                                                         # [B, S, F]
-        rank_row = col[0] + jnp.sum(ins_below_row.astype(jnp.int32), axis=1)
-        before = jnp.cumsum(act.astype(jnp.int32), axis=1) - act.astype(
-            jnp.int32
-        )                                                         # [B, S]
-        row_below_ins = validr[:, None, :] & _lt_planes(
-            khi[:, None, :], klo[:, None, :], ikh[:, :, None], ikl[:, :, None]
-        )                                                         # [B, S, F]
-        rank_ins = before + jnp.sum(row_below_ins.astype(jnp.int32), axis=2)
+        rank_row = col[0] + _count(ins_below_row, axis=1)
+        before = _exclusive_prefix_count(act)                     # [B, S]
+        row_below_ins = validr3 & _lt_planes(khr, klr, ikh3, ikl3)
+        rank_ins = before + _count(row_below_ins, axis=2)         # [B, S]
 
         # cut point: m <= F keeps everything left; m > F cuts at m // 2
         m = (
-            jnp.sum(validr.astype(jnp.int32), axis=-1)
-            + jnp.sum(act.astype(jnp.int32), axis=-1)
-        )                                                         # [B]
-        split = m > fanout
-        left_n = jnp.where(split, m // 2, m)                      # [B]
+            _count(validr, axis=-1, keepdims=True)
+            + _count(act, axis=-1, keepdims=True)
+        )                                                         # [B, 1]
+        split = m > np.int32(fanout)
+        left_n = jnp.where(split, m // np.int32(2), m)            # [B, 1]
 
         out_col = jax.lax.broadcasted_iota(jnp.int32, (1, fanout, 1), 1)
-        ln = left_n[:, None, None]
+        ln = left_n[:, :, None]                                   # [B, 1, 1]
 
         def gather(sel_rank_row, sel_rank_ins, target):
             """One-hot gather of elements whose shifted rank hits ``target``
             output columns; returns the pick masks [B, F, F|S]."""
-            pr = validr[:, None, :] & (sel_rank_row[:, None, :] == target)
-            pi = act[:, None, :] & (sel_rank_ins[:, None, :] == target)
+            pr = validr3 & (sel_rank_row[:, None, :] == target)
+            pi = act3 & (sel_rank_ins[:, None, :] == target)
             return pr, pi
 
         # left side: rank < left_n at column rank
@@ -104,23 +114,22 @@ def _make_kernel(fanout: int):
         keep_l = out_col < ln
         pick_row_l = pick_row_l & keep_l
         pick_ins_l = pick_ins_l & keep_l
-        # right side: rank >= left_n at column rank - left_n
+        # right side: rank >= left_n at column rank - left_n; lanes that do
+        # not split shift their ranks out of every column
+        shift = jnp.where(split, left_n, np.int32(2 * fanout))    # [B, 1]
         pick_row_r, pick_ins_r = gather(
-            rank_row - left_n[:, None], rank_ins - left_n[:, None], out_col
+            rank_row - shift, rank_ins - shift, out_col
         )
-        keep_r = split[:, None, None]
-        pick_row_r = pick_row_r & keep_r
-        pick_ins_r = pick_ins_r & keep_r
 
-        hit_l = jnp.any(pick_row_l, axis=-1) | jnp.any(pick_ins_l, axis=-1)
-        hit_r = jnp.any(pick_row_r, axis=-1) | jnp.any(pick_ins_r, axis=-1)
+        hit_l = _any(pick_row_l, axis=-1) | _any(pick_ins_l, axis=-1)
+        hit_r = _any(pick_row_r, axis=-1) | _any(pick_ins_r, axis=-1)
 
         def compact(pick_row, pick_ins, hit, plane_row, plane_ins, fill):
             got = jnp.sum(
-                jnp.where(pick_row, plane_row[:, None, :], 0), axis=-1,
+                jnp.where(pick_row, plane_row[:, None, :], _ZERO), axis=-1,
                 dtype=jnp.int32,
             ) + jnp.sum(
-                jnp.where(pick_ins, plane_ins[:, None, :], 0), axis=-1,
+                jnp.where(pick_ins, plane_ins[:, None, :], _ZERO), axis=-1,
                 dtype=jnp.int32,
             )
             return jnp.where(hit, got, fill)
@@ -131,26 +140,31 @@ def _make_kernel(fanout: int):
         ivl = ivl_ref[...]
         lkh_ref[...] = compact(pick_row_l, pick_ins_l, hit_l, khi, ikh, _KMAX_HI)
         lkl_ref[...] = compact(pick_row_l, pick_ins_l, hit_l, klo, ikl, _KMAX_LO)
-        lvh_ref[...] = compact(pick_row_l, pick_ins_l, hit_l, vhi, ivh, 0)
-        lvl_ref[...] = compact(pick_row_l, pick_ins_l, hit_l, vlo, ivl, 0)
+        lvh_ref[...] = compact(pick_row_l, pick_ins_l, hit_l, vhi, ivh, _ZERO)
+        lvl_ref[...] = compact(pick_row_l, pick_ins_l, hit_l, vlo, ivl, _ZERO)
         rkh_ref[...] = compact(pick_row_r, pick_ins_r, hit_r, khi, ikh, _KMAX_HI)
         rkl_ref[...] = compact(pick_row_r, pick_ins_r, hit_r, klo, ikl, _KMAX_LO)
-        rvh_ref[...] = compact(pick_row_r, pick_ins_r, hit_r, vhi, ivh, 0)
-        rvl_ref[...] = compact(pick_row_r, pick_ins_r, hit_r, vlo, ivl, 0)
-        occl_ref[...] = jnp.sum(hit_l, axis=-1, dtype=jnp.int32)
-        occr_ref[...] = jnp.sum(hit_r, axis=-1, dtype=jnp.int32)
+        rvh_ref[...] = compact(pick_row_r, pick_ins_r, hit_r, vhi, ivh, _ZERO)
+        rvl_ref[...] = compact(pick_row_r, pick_ins_r, hit_r, vlo, ivl, _ZERO)
+        occl_ref[...] = _count(hit_l, axis=-1, keepdims=True)
+        occr_ref[...] = _count(hit_r, axis=-1, keepdims=True)
 
         # separator = the merged element of rank left_n (right row's head)
-        sep_row = validr & (rank_row == left_n[:, None])          # [B, F]
-        sep_ins = act & (rank_ins == left_n[:, None])             # [B, S]
+        sep_row = validr & (rank_row == left_n)                   # [B, F]
+        sep_ins = act & (rank_ins == left_n)                      # [B, S]
 
         def pick_sep(plane_row, plane_ins, fill):
             got = jnp.sum(
-                jnp.where(sep_row, plane_row, 0), axis=-1, dtype=jnp.int32
+                jnp.where(sep_row, plane_row, _ZERO), axis=-1, keepdims=True,
+                dtype=jnp.int32,
             ) + jnp.sum(
-                jnp.where(sep_ins, plane_ins, 0), axis=-1, dtype=jnp.int32
+                jnp.where(sep_ins, plane_ins, _ZERO), axis=-1, keepdims=True,
+                dtype=jnp.int32,
             )
-            has = jnp.any(sep_row, axis=-1) | jnp.any(sep_ins, axis=-1)
+            has = (
+                _count(sep_row, axis=-1, keepdims=True)
+                + _count(sep_ins, axis=-1, keepdims=True)
+            ) > _ZERO
             return jnp.where(split & has, got, fill)
 
         sep_hi_ref[...] = pick_sep(khi, ikh, _KMAX_HI)
@@ -167,7 +181,7 @@ def leaf_split(
     ins_key: jax.Array,  # [Q, S] int64 staged insert keys (KEY_MAX inactive)
     ins_val: jax.Array,  # [Q, S] int64 staged insert values
     *,
-    interpret: bool = True,
+    interpret: "bool | None" = None,
     block_b: int = BLOCK_B,
 ):
     """Merge staged inserts into each leaf row, splitting rows that
@@ -191,9 +205,9 @@ def leaf_split(
     ivh, ivl = _split_i64(ins_val)
 
     grid = (qp // block_b,)
-    row = pl.BlockSpec((block_b, f), lambda i: (i, 0))
-    staged = pl.BlockSpec((block_b, s), lambda i: (i, 0))
-    lane = pl.BlockSpec((block_b,), lambda i: (i,))
+    row = pl.BlockSpec((block_b, f), _row_block)
+    staged = pl.BlockSpec((block_b, s), _row_block)
+    lane = pl.BlockSpec((block_b, 1), _row_block)
     outs = pl.pallas_call(
         _make_kernel(f),
         grid=grid,
@@ -201,8 +215,8 @@ def leaf_split(
         out_specs=[row, row, row, row, row, row, row, row,
                    lane, lane, lane, lane, lane],
         out_shape=[jax.ShapeDtypeStruct((qp, f), jnp.int32)] * 8
-        + [jax.ShapeDtypeStruct((qp,), jnp.int32)] * 5,
-        interpret=interpret,
+        + [jax.ShapeDtypeStruct((qp, 1), jnp.int32)] * 5,
+        interpret=use_interpret() if interpret is None else interpret,
     )(khi, klo, vhi, vlo, ikh, ikl, ivh, ivl)
     lkh, lkl, lvh, lvl, rkh, rkl, rvh, rvl, occl, occr, sh, sl, did = outs
     return (
@@ -210,8 +224,8 @@ def leaf_split(
         _join_i64(lvh, lvl)[:q],
         _join_i64(rkh, rkl)[:q],
         _join_i64(rvh, rvl)[:q],
-        occl[:q],
-        occr[:q],
-        _join_i64(sh, sl)[:q],
-        did[:q],
+        occl[:q, 0],
+        occr[:q, 0],
+        _join_i64(sh, sl)[:q, 0],
+        did[:q, 0],
     )

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import use_interpret
+
 DEFAULT_BLOCK_D = 128
 
 
@@ -52,7 +54,7 @@ def mamba_scan(
     C: jax.Array,      # [B, L, N]
     x: jax.Array,      # [B, L, D]
     *,
-    interpret: bool = True,
+    interpret: "bool | None" = None,
     block_d: int = DEFAULT_BLOCK_D,
 ):
     b, l, d = x.shape
@@ -76,6 +78,6 @@ def mamba_scan(
         out_specs=pl.BlockSpec((1, l, bd), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, l, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
-        interpret=interpret,
+        interpret=use_interpret() if interpret is None else interpret,
     )(delta, A[None], Bmat, C, x)
     return out

@@ -21,6 +21,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.nodes import FANOUT
 from repro.core.pool import SEP_SUFFIX_SENTINEL
+from repro.kernels import use_interpret
 
 BLOCK_B = 256
 
@@ -66,7 +67,7 @@ def node_search(
     queries: jax.Array,     # [B] int64
     node_values: jax.Array, # [B, FANOUT] int64
     *,
-    interpret: bool = True,
+    interpret: "bool | None" = None,
     block_b: int = BLOCK_B,
 ):
     """Batched lower-bound + exact-match.  Returns (slot, found, value)."""
@@ -104,7 +105,7 @@ def node_search(
             jax.ShapeDtypeStruct((bp,), jnp.bool_),
             jax.ShapeDtypeStruct((bp, 2), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=use_interpret() if interpret is None else interpret,
     )(khi, klo, qhi, qlo, vplanes)
     slot, found, vpl = out
     value = (vpl[:, 0].astype(jnp.int64) << 32) | (
@@ -163,7 +164,7 @@ def node_search_prefix(
     node_keys: jax.Array,   # [B, FANOUT] int64 canonical rows (fallback)
     queries: jax.Array,     # [B] int64
     *,
-    interpret: bool = True,
+    interpret: "bool | None" = None,
     block_b: int = BLOCK_B,
 ):
     """Batched lower-bound over prefix-compressed separator rows
@@ -209,6 +210,6 @@ def node_search_prefix(
         ],
         out_specs=pl.BlockSpec((block_b,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((bp,), jnp.int32),
-        interpret=interpret,
+        interpret=use_interpret() if interpret is None else interpret,
     )(phi, plo, nbits.astype(jnp.int32), suffix, khi, klo, qhi, qlo)
     return slot[:b]

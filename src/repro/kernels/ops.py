@@ -1,14 +1,13 @@
 """Public jit'd entry points for every Pallas kernel.
 
-``interpret`` defaults to True so the whole framework runs on CPU; the
-launcher flips it to False on real TPU backends (see launch/train.py).
+``interpret`` defaults to None, resolved by :func:`use_interpret`: the
+compiled kernel on a TPU backend, the Pallas interpreter elsewhere.
 Oracles live in kernels/ref.py with identical signatures.
 """
 
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import use_interpret
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.leaf_scan import leaf_scan
 from repro.kernels.leaf_split import leaf_split
@@ -29,9 +28,3 @@ __all__ = [
     "subtree_walk",
     "use_interpret",
 ]
-
-
-def use_interpret() -> bool:
-    """Kernels execute their Python bodies (interpret mode) unless a real
-    TPU backend is present."""
-    return jax.default_backend() != "tpu"

@@ -22,6 +22,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import use_interpret
+
 NEG_INF = -1e30
 
 
@@ -80,7 +82,7 @@ def paged_attention(
     page_table: jax.Array,  # [B, pages_per_req] int32 (DEX-resolved)
     seq_lens: jax.Array,  # [B] int32
     *,
-    interpret: bool = True,
+    interpret: "bool | None" = None,
 ):
     b, h, d = q.shape
     _, page, hkv, _ = k_pages.shape
@@ -125,6 +127,6 @@ def paged_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-        interpret=interpret,
+        interpret=use_interpret() if interpret is None else interpret,
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), qg, k_pages, v_pages)
     return out.reshape(b, h, d)

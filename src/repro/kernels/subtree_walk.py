@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.nodes import FANOUT
+from repro.kernels import use_interpret
 
 BLOCK_B = 128
 
@@ -117,17 +118,21 @@ def _make_kernel(levels: int, c_nodes: int):
     jax.jit, static_argnames=("levels", "interpret", "block_b")
 )
 def subtree_walk(
-    block_keys: jax.Array,      # [C, FANOUT] int64
+    block_keys: jax.Array,      # [C, FANOUT] int64 (or a [C * FANOUT] row)
     block_children: jax.Array,  # [C, FANOUT] int32
     block_values: jax.Array,    # [C, FANOUT] int64
     queries: jax.Array,         # [B] int64
     *,
     levels: int,
-    interpret: bool = True,
+    interpret: "bool | None" = None,
     block_b: int = BLOCK_B,
 ):
     """Walk one subtree block for a batch of queries.  Returns
     (found [B] bool, values [B] int64)."""
+    block_keys, block_children, block_values = (
+        x.reshape(-1, FANOUT) for x in (block_keys, block_children,
+                                        block_values)
+    )
     c_nodes = block_keys.shape[0]
     b = queries.shape[0]
     pad = (-b) % block_b
@@ -154,7 +159,7 @@ def subtree_walk(
             jax.ShapeDtypeStruct((bp,), jnp.int32),
             jax.ShapeDtypeStruct((bp,), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=use_interpret() if interpret is None else interpret,
     )(*kp, *cp, *vp, qhi, qlo)
     values = (vhi.astype(jnp.int64) << 32) | (
         vlo.astype(jnp.uint32).astype(jnp.int64)

@@ -568,7 +568,8 @@ class TestFleetCachePolicyGoldens:
         stats = np.asarray(state.stats)
         return {
             "results": res_h.hexdigest()[:16],
-            "state": _digest(state.pool.pool_keys, state.pool.pool_values,
+            "state": _digest(pool_mod.blocks(state.pool.pool_keys),
+                             pool_mod.blocks(state.pool.pool_values),
                              state.versions, state.occupancy),
             "stats12": _digest(stats[:, :12]),
         }, stats
@@ -621,7 +622,8 @@ class TestFleetCachePolicyGoldens:
                          .encode())
         got = {
             "results": res_h.hexdigest()[:16],
-            "state": _digest(s_pipe.pool.pool_keys, s_pipe.pool.pool_values,
+            "state": _digest(pool_mod.blocks(s_pipe.pool.pool_keys),
+                             pool_mod.blocks(s_pipe.pool.pool_values),
                              s_pipe.versions, s_pipe.occupancy),
             "stats12": _digest(np.asarray(s_pipe.stats)[:, :12]),
         }
