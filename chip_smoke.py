@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
 """Chip smoke test: the DEX engine's served path, compiled, on a TPU.
 
-Builds a YCSB dataset of ``--keys`` keys (default 10^8) from ``--seed``,
-bulk-loads the subtree-blocked pool onto the chip(s) and drives the jitted
+Builds a YCSB dataset of 10^8 keys from ``--seed``, bulk-loads the
+subtree-blocked pool onto the chip(s), each shard copied from the host
+straight to its device, and drives the jitted
 mixed-op engine (``core/engine.make_dex_engine`` with its Pallas leaf
 kernels) through a warm batch, YCSB-A, YCSB-E and an insert batch dense
 enough to overflow leaves, whose splits the on-mesh SMO engine settles
@@ -43,6 +44,7 @@ from repro.core.partition import LogicalPartitions  # noqa: E402
 from repro.core.write import STATUS_MISS, STATUS_OK, STATUS_SPLIT  # noqa: E402
 from repro.data import ycsb  # noqa: E402
 
+N_KEYS = 10**8
 BATCH = 1024
 MAX_COUNT = 128
 THETA = 0.99
@@ -198,13 +200,17 @@ def _drive(n_chips, n_keys, seed, n_batches, log, compiles) -> dict:
 
     # ---- data and state -------------------------------------------------
     keys = ycsb.make_dataset(n_keys, seed=seed)
-    pool, meta = pool_mod.build_pool(keys, keys * VALUE_MUL, level_m=1,
-                                     fill=0.7, n_shards=cfg.n_memory)
+    pool, meta = pool_mod.build_pool_host(keys, keys * VALUE_MUL, level_m=1,
+                                          fill=0.7, n_shards=cfg.n_memory)
     bounds = LogicalPartitions.from_samples(
         keys[:: max(1, keys.size // 100_000)], cfg.n_route
     ).boundaries
+    shardings = dex.state_shardings(mesh, cfg)
+    # the host planes go shard by shard to their devices: no device ever
+    # stages the whole pool
+    pool = jax.device_put(pool, shardings.pool)
     state = jax.device_put(dex.init_state(pool, meta, cfg, bounds),
-                           dex.state_shardings(mesh, cfg))
+                           shardings)
     del pool
     state = jax.block_until_ready(state)
     pool_leaves = jax.tree.leaves(state.pool)
@@ -388,7 +394,6 @@ def _drive(n_chips, n_keys, seed, n_batches, log, compiles) -> dict:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
-    ap.add_argument("--keys", type=int, default=10**8)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -401,14 +406,14 @@ def main(argv=None) -> int:
         jax.config.update("jax_compilation_cache_dir",
                           os.path.join(ROOT, ".jax_cache"))
 
-    summary = run(args.chips, args.keys, args.seed)
+    summary = run(args.chips, N_KEYS, args.seed)
     missing = [k for k, n in summary["tpu_custom_calls"].items() if n == 0]
     if missing:
         raise SmokeError(f"no Pallas kernel compiled into {missing}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev0.platform,
         "kind": dev0.device_kind,
-        "count": len(jax.devices()),
+        "count": summary["chips"],
     }}))
     return 0
 

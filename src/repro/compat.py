@@ -19,10 +19,12 @@ def shard_map_compat(f, mesh, in_specs, out_specs):
     )
 
 
-def make_mesh_compat(axis_shapes, axis_names):
-    """``jax.make_mesh`` with every axis ``Auto``-typed."""
+def make_mesh_compat(axis_shapes, axis_names, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``-typed, over ``devices``
+    (all devices present when None)."""
     return jax.make_mesh(
         axis_shapes,
         axis_names,
         axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices,
     )

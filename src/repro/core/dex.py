@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.compat import make_mesh_compat
 from repro.core.fleet_cache import (  # noqa: F401  (re-exported compat names)
     P_ADMIT_LEAF_PCT,
     DexCache,
@@ -217,10 +218,8 @@ def make_dex_mesh(devices: "Sequence[jax.Device] | None" = None):
     devices = list(jax.devices() if devices is None else devices)
     n = len(devices)
     n_route = max(d for d in range(1, math.isqrt(n) + 1) if n % d == 0)
-    return jax.make_mesh(
-        (n_route, n // n_route), ("data", "model"),
-        axis_types=(jax.sharding.AxisType.Auto,) * 2, devices=devices,
-    )
+    return make_mesh_compat((n_route, n // n_route), ("data", "model"),
+                            devices=devices)
 
 
 def state_shardings(mesh, cfg: DexMeshConfig):

@@ -20,8 +20,8 @@ VMEM-resident block.
 read and write it and :func:`blocks` gives the ``[S, C, FANOUT]`` view.  A
 ``[S, C, 64]`` array has no lane-dense TPU layout (64 pads to 128 lanes), so
 XLA lays such a pool out subtree-minor and re-lays the whole of it out for
-every row gather and scatter — about three pool-sized temporaries per
-engine step, which kept a 10^8-key pool off one 16 GB chip.  Block rows are
+every row gather and scatter (about three pool-sized temporaries per
+engine step by the TPU compiler's memory analysis).  Block rows are
 a multiple of 128 lanes, and a row gather reads 64 lanes in place.
 
 **Free-list headroom (the on-mesh SMO allocation layer).**  Each block is
@@ -128,7 +128,15 @@ def _level_offsets(
 DEFAULT_HEADROOM = 0.5
 
 
-def build_pool(
+def build_pool(keys: np.ndarray, values: Optional[np.ndarray] = None,
+               **kw) -> Tuple[SubtreePool, PoolMeta]:
+    """:func:`build_pool_host` with the pool's planes on the default
+    device."""
+    pool, meta = build_pool_host(keys, values, **kw)
+    return jax.tree.map(jnp.asarray, pool), meta
+
+
+def build_pool_host(
     keys: np.ndarray,
     values: Optional[np.ndarray] = None,
     *,
@@ -138,7 +146,9 @@ def build_pool(
     headroom: float = DEFAULT_HEADROOM,
     subtree_leaves: Optional[int] = None,
 ) -> Tuple[SubtreePool, PoolMeta]:
-    """Bulk-build the blocked pool from sorted unique keys.
+    """Bulk-build the blocked pool from sorted unique keys, as host numpy
+    planes (``jax.device_put`` them under a sharding to place each shard
+    straight on its device).
 
     ``n_shards``: pad the subtree axis to a multiple of this (the `model`
     mesh axis size) so the arrays block-shard evenly.  ``headroom``: extra
@@ -250,11 +260,11 @@ def build_pool(
     TC = np.stack(top_c_rows) if top_c_rows else np.full((1, FANOUT), NULL, np.int32)
 
     pool = SubtreePool(
-        top_keys=jnp.asarray(TK),
-        top_children=jnp.asarray(TC),
-        pool_keys=jnp.asarray(PK.reshape(S, cap * FANOUT)),
-        pool_children=jnp.asarray(PC.reshape(S, cap * FANOUT)),
-        pool_values=jnp.asarray(PV.reshape(S, cap * FANOUT)),
+        top_keys=TK,
+        top_children=TC,
+        pool_keys=PK.reshape(S, cap * FANOUT),
+        pool_children=PC.reshape(S, cap * FANOUT),
+        pool_values=PV.reshape(S, cap * FANOUT),
     )
     meta = PoolMeta(
         level_m=level_m,

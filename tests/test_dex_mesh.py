@@ -34,6 +34,17 @@ class TestSubtreePool:
         np.testing.assert_array_equal(found, expect)
         np.testing.assert_array_equal(vals[expect], q[expect] * 3)
 
+    def test_host_build_matches_device_build(self):
+        keys = _dataset(3000, seed=7)
+        host, meta_h = pool_mod.build_pool_host(keys, keys * 3, level_m=1,
+                                                n_shards=2)
+        dev, meta_d = pool_mod.build_pool(keys, keys * 3, level_m=1,
+                                          n_shards=2)
+        assert meta_h == meta_d
+        for h, d in zip(host, dev):
+            assert isinstance(h, np.ndarray)
+            np.testing.assert_array_equal(h, np.asarray(d))
+
     def test_single_subtree(self):
         keys = np.arange(1, 30, dtype=np.int64)
         pool, meta = pool_mod.build_pool(keys, level_m=1, n_shards=1)
