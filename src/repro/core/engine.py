@@ -107,6 +107,7 @@ a performance artifact, not a correctness one.
 
 from __future__ import annotations
 
+import contextlib
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -181,6 +182,35 @@ def scan_hops(meta: PoolMeta, max_count: int) -> int:
     only the static loop bound — per-lane collected-count masking stops each
     lane's remote reads as soon as its count is covered."""
     return 1 + -(-max_count // meta.min_leaf_fill)
+
+
+def distinct_fetched_per_column(gid, fetched, n_nodes: int, subtree_cap: int,
+                                s_per: int, n_memory: int) -> jax.Array:
+    """Distinct node ids among the ``fetched`` lanes, counted per memory
+    column (``(gid // subtree_cap) // s_per``) as ``[n_memory]`` float32:
+    the realized side of the offload audit (DESIGN.md §12), since the mesh
+    coalesces duplicate gids into one message.  The other lanes are masked
+    to the ``n_nodes`` sentinel; the gids are sorted and the first of each
+    run counts once, so the cost is O(lanes), not O(n_nodes).  Node ids
+    are sorted and divided as int32 (the chip has no 64-bit lanes): every
+    chip holds a version per node of the pool, so they fit."""
+    g = jnp.where(fetched & (gid < n_nodes), gid, n_nodes).astype(jnp.int32)
+    g = jnp.sort(g)
+    first = jnp.concatenate([jnp.ones((1,), bool), g[1:] != g[:-1]])
+    first = first & (g < n_nodes)
+    col = jnp.where(first, (g // subtree_cap) // s_per, 0).astype(jnp.int32)
+    return jnp.zeros((n_memory,), jnp.float32).at[col].add(
+        first.astype(jnp.float32)
+    )
+
+
+@contextlib.contextmanager
+def _lat_scope(part: str):
+    """A block of the modelled-cost ledger (obs/latency.py): its ops under
+    ``dex/lat/<part>``, and any collective traced in it counted under the
+    ``dex/lat`` phase, where benchmarks assert there is none."""
+    with jax.named_scope("dex/lat/" + part), routing.trace_phase("dex/lat"):
+        yield
 
 
 class EngineResult(NamedTuple):
@@ -416,79 +446,98 @@ def make_dex_engine(
         the descent observed, for the back half's overlap-window check."""
         b = keys.shape[0]
         n_route = cfg.n_route
-        vers = versions[0]
-        succ_t = succ[0]
-        n_nodes_total = vers.shape[0]
 
+        # Every op of the step sits under exactly one ``dex/<family>`` scope
+        # (``plan["phases"]``), never one inside another: a profiler trace
+        # attributes an op to the first ``dex/`` component of its op_name.
         # --- 1. ONE shared route round for every opcode --------------------
-        dev = routing.device_linear_index(cfg, mesh)
-        lane_prio = dev.astype(jnp.int64) * b + jnp.arange(b, dtype=jnp.int64)
-        # phase-offset priority: all updates replay before all inserts, the
-        # phased batch order the host-mirror validation uses
-        phase = jnp.where(
-            opcodes == OP_INSERT, jnp.int64(cfg.n_devices) * b, jnp.int64(0)
-        )
-        prio0 = lane_prio + phase
-        owner, dem = routing.route_owners(boundaries, keys, n_route)
-        new_demand = demand + dem
+        with jax.named_scope("dex/lanes"):
+            vers = versions[0]
+            succ_t = succ[0]
+            dev = routing.device_linear_index(cfg, mesh)
+            lane_prio = (
+                dev.astype(jnp.int64) * b + jnp.arange(b, dtype=jnp.int64)
+            )
+            # phase-offset priority: all updates replay before all inserts,
+            # the phased batch order the host-mirror validation uses
+            phase = jnp.where(
+                opcodes == OP_INSERT, jnp.int64(cfg.n_devices) * b,
+                jnp.int64(0),
+            )
+            prio0 = lane_prio + phase
+        n_nodes_total = vers.shape[0]
+        with jax.named_scope("dex/locate"):
+            owner, dem = routing.route_owners(boundaries, keys, n_route)
+            new_demand = demand + dem
         cap = routing.route_capacity(b, n_route, cfg.route_capacity_factor)
-        payload = jnp.stack(
-            [keys, values, opcodes.astype(jnp.int64), prio0], axis=-1
-        )                                                   # [B, 4]
-        buf, lane, dropped_r = routing.pack_by_dest(payload, owner, n_route, cap)
-        # inactive lanes share the OOB sentinel bucket; its overflow is
-        # meaningless (see routing.route_owners)
-        dropped_r = dropped_r & (keys != KEY_MAX)
+        with jax.named_scope("dex/lanes"):
+            payload = jnp.stack(
+                [keys, values, opcodes.astype(jnp.int64), prio0], axis=-1
+            )                                               # [B, 4]
+            buf, lane, dropped_r = routing.pack_by_dest(
+                payload, owner, n_route, cap
+            )
+            # inactive lanes share the OOB sentinel bucket; its overflow is
+            # meaningless (see routing.route_owners)
+            dropped_r = dropped_r & (keys != KEY_MAX)
         with jax.named_scope("dex/route"):
             routed = routing.route_exchange(buf, cfg, mesh)  # [n_route, cap, 4]
-        q = routed[..., 0].reshape(-1)                      # [Q]
-        val = routed[..., 1].reshape(-1)
-        opc = routed[..., 2].reshape(-1).astype(jnp.int32)
-        pr = routed[..., 3].reshape(-1)
-        live = q != KEY_MAX
-        is_scan = live & (opc == OP_SCAN) if has_scan else jnp.zeros(q.shape, bool)
+        with jax.named_scope("dex/lanes"):
+            q = routed[..., 0].reshape(-1)                  # [Q]
+            val = routed[..., 1].reshape(-1)
+            opc = routed[..., 2].reshape(-1).astype(jnp.int32)
+            pr = routed[..., 3].reshape(-1)
+            live = q != KEY_MAX
+            is_scan = (
+                live & (opc == OP_SCAN) if has_scan
+                else jnp.zeros(q.shape, bool)
+            )
 
         # --- 2. replicated top-tree walk + per-group offload decision ------
-        subtree = top_walk(pool, meta, q)
-        subtree = jnp.where(live, subtree, 0)
-        col = (subtree // s_per).astype(jnp.int32)
-        ema = miss_ema[0]                                   # [n_mem, levels]
-        if has_offloadable and cfg.policy == "auto":
-            # group = destination memory column; live counts are psum'd so
-            # the decision is uniform across devices (and countable once)
-            offable = live & ~is_scan
-            n_live_c = (
-                jnp.zeros((cfg.n_memory,), jnp.int64)
-                .at[col].add(offable.astype(jnp.int64))
+        with jax.named_scope("dex/locate"):
+            subtree = top_walk(pool, meta, q)
+            subtree = jnp.where(live, subtree, 0)
+            col = (subtree // s_per).astype(jnp.int32)
+        with jax.named_scope("dex/offload"):
+            ema = miss_ema[0]                               # [n_mem, levels]
+            if has_offloadable and cfg.policy == "auto":
+                # group = destination memory column; live counts are psum'd
+                # so the decision is uniform across devices (and countable
+                # once)
+                offable = live & ~is_scan
+                n_live_c = (
+                    jnp.zeros((cfg.n_memory,), jnp.int64)
+                    .at[col].add(offable.astype(jnp.int64))
+                )
+                n_live_c = jax.lax.psum(n_live_c, cfg.all_axes)
+                nf = n_live_c.astype(jnp.float32)
+                caps = jnp.minimum(
+                    nf[:, None], jnp.asarray(level_nodes, jnp.float32)[None, :]
+                )                                           # [n_mem, levels]
+                fetch_cost = (
+                    jnp.sum(caps * ema, axis=-1) * NODE_ROW_BYTES
+                    * cfg.offload_c
+                )
+                rpc_cost = nf * float(OFFLOAD_REQ_BYTES + OFFLOAD_RESP_BYTES)
+                want_off_c = fetch_cost > rpc_cost          # [n_mem] bool
+                grp_live = n_live_c > 0
+            elif has_offloadable and cfg.policy == "offload":
+                offable = live & ~is_scan
+                n_live_c = (
+                    jnp.zeros((cfg.n_memory,), jnp.int64)
+                    .at[col].add(offable.astype(jnp.int64))
+                )
+                n_live_c = jax.lax.psum(n_live_c, cfg.all_axes)
+                want_off_c = jnp.ones((cfg.n_memory,), bool)
+                grp_live = n_live_c > 0
+            else:
+                want_off_c = jnp.zeros((cfg.n_memory,), bool)
+                grp_live = jnp.zeros((cfg.n_memory,), bool)
+            offl = want_off_c[col] & live & ~is_scan if has_offloadable else (
+                jnp.zeros(q.shape, bool)
             )
-            n_live_c = jax.lax.psum(n_live_c, cfg.all_axes)
-            nf = n_live_c.astype(jnp.float32)
-            caps = jnp.minimum(
-                nf[:, None], jnp.asarray(level_nodes, jnp.float32)[None, :]
-            )                                               # [n_mem, levels]
-            fetch_cost = (
-                jnp.sum(caps * ema, axis=-1) * NODE_ROW_BYTES * cfg.offload_c
-            )
-            rpc_cost = nf * float(OFFLOAD_REQ_BYTES + OFFLOAD_RESP_BYTES)
-            want_off_c = fetch_cost > rpc_cost              # [n_mem] bool
-            grp_live = n_live_c > 0
-        elif has_offloadable and cfg.policy == "offload":
-            offable = live & ~is_scan
-            n_live_c = (
-                jnp.zeros((cfg.n_memory,), jnp.int64)
-                .at[col].add(offable.astype(jnp.int64))
-            )
-            n_live_c = jax.lax.psum(n_live_c, cfg.all_axes)
-            want_off_c = jnp.ones((cfg.n_memory,), bool)
-            grp_live = n_live_c > 0
-        else:
-            want_off_c = jnp.zeros((cfg.n_memory,), bool)
-            grp_live = jnp.zeros((cfg.n_memory,), bool)
-        offl = want_off_c[col] & live & ~is_scan if has_offloadable else (
-            jnp.zeros(q.shape, bool)
-        )
-        n_off_groups = jnp.sum(want_off_c & grp_live).astype(jnp.int64)
-        n_fetch_groups = jnp.sum(~want_off_c & grp_live).astype(jnp.int64)
+            n_off_groups = jnp.sum(want_off_c & grp_live).astype(jnp.int64)
+            n_fetch_groups = jnp.sum(~want_off_c & grp_live).astype(jnp.int64)
 
         # --- leaf-direct route-table probe (DESIGN.md §13) -----------------
         # one searchsorted over the replicated trained table maps the key
@@ -500,18 +549,20 @@ def make_dex_engine(
         # — so a stale, partial or poisoned table costs mispredict counts,
         # never answers.  Scans keep their full descent (their window
         # machinery consumes the descent's leaf row anyway).
-        acc = jnp.zeros(q.shape, bool)
-        n_rt_skips = jnp.int64(0)
-        n_rt_mis = jnp.int64(0)
-        if use_rt:
-            ridx, p_sub, p_loc = routing.rt_predict(rtk, rts, rtl, q)
-            elig = live & ~is_scan & ~offl
-            rt_guess, acc, _pred_gid = fleet_cache.rt_accept(
-                meta, rtk, rth, rts, rtl, rtv, vers, ridx, subtree, q, elig,
-            )
-            n_rt_mis = jnp.sum(rt_guess & ~acc).astype(jnp.int64)
-            # an accepted lane skips all inner levels within the subtree
-            n_rt_skips = jnp.sum(acc).astype(jnp.int64) * (levels - 1)
+        with jax.named_scope("dex/locate"):
+            acc = jnp.zeros(q.shape, bool)
+            n_rt_skips = jnp.int64(0)
+            n_rt_mis = jnp.int64(0)
+            if use_rt:
+                ridx, p_sub, p_loc = routing.rt_predict(rtk, rts, rtl, q)
+                elig = live & ~is_scan & ~offl
+                rt_guess, acc, _pred_gid = fleet_cache.rt_accept(
+                    meta, rtk, rth, rts, rtl, rtv, vers, ridx, subtree, q,
+                    elig,
+                )
+                n_rt_mis = jnp.sum(rt_guess & ~acc).astype(jnp.int64)
+                # an accepted lane skips all inner levels within the subtree
+                n_rt_skips = jnp.sum(acc).astype(jnp.int64) * (levels - 1)
 
         # --- per-lane cost ledger + offload cost-model audit ----------------
         # (obs/latency.py, DESIGN.md §12).  ``cost`` accumulates the modeled
@@ -520,81 +571,86 @@ def make_dex_engine(
         # half; ``fmiss`` remembers whether any level paid a remote fetch
         # (the remote_fetch path bit).  The replicated top walk prices like
         # the simulator's warm top-tree cache hits.
-        cost = live.astype(jnp.float32) * (
-            obs_latency.T_CACHED * float(meta.top_height)
-        )
-        fmiss = jnp.zeros(q.shape, bool)
-        audit = has_offloadable and cfg.policy == "auto"
-        a_upd = jnp.zeros((2, cfg.n_memory, levels), jnp.float32)
-        if audit:
-            # predicted fetch bytes per (column, level) under the EMA rule,
-            # recorded for the columns the model actually priced onto the
-            # fetch side; the decision is mesh-global (psum'd counts), so
-            # device 0 records it once
-            pred_cl = caps * ema * NODE_ROW_BYTES * cfg.offload_c
-            fetch_dec = (grp_live & ~want_off_c).astype(jnp.float32)
-            a_upd = a_upd.at[0].set(
-                (dev == 0).astype(jnp.float32) * fetch_dec[:, None] * pred_cl
+        with _lat_scope("price"):
+            cost = live.astype(jnp.float32) * (
+                obs_latency.T_CACHED * float(meta.top_height)
             )
-            # realized bytes count *distinct* fetched nodes per (column,
-            # level) — the mesh coalesces duplicate gids into one message —
-            # via a node bitmap reduced along the node -> column map
-            node_col = (
-                (jnp.arange(n_nodes_total) // meta.subtree_cap) // s_per
-            ).astype(jnp.int32)
+            fmiss = jnp.zeros(q.shape, bool)
+        audit = has_offloadable and cfg.policy == "auto"
+        with _lat_scope("audit"):
+            a_upd = jnp.zeros((2, cfg.n_memory, levels), jnp.float32)
+            if audit:
+                # predicted fetch bytes per (column, level) under the EMA
+                # rule, recorded for the columns the model actually priced
+                # onto the fetch side; the decision is mesh-global (psum'd
+                # counts), so device 0 records it once.  The realized bytes
+                # (distinct fetched nodes) are added level by level below.
+                pred_cl = caps * ema * NODE_ROW_BYTES * cfg.offload_c
+                fetch_dec = (grp_live & ~want_off_c).astype(jnp.float32)
+                a_upd = a_upd.at[0].set(
+                    (dev == 0).astype(jnp.float32) * fetch_dec[:, None]
+                    * pred_cl
+                )
 
         # --- 3. ONE shared version-checked cached descent ------------------
-        fetchable = live & ~offl
-        local = jnp.zeros(q.shape, jnp.int32)
         new_cache = cache
-        n_fetch = jnp.int64(0)
-        n_hit = jnp.int64(0)
-        shed = jnp.zeros(q.shape, bool)
-        found_leaf = jnp.zeros(q.shape, bool)
-        vals_leaf = jnp.zeros(q.shape, jnp.int64)
-        rows_k_leaf = jnp.full(q.shape + (FANOUT,), KEY_MAX, jnp.int64)
-        rows_v_leaf = jnp.zeros(q.shape + (FANOUT,), jnp.int64)
-        miss_cl = jnp.zeros((cfg.n_memory, levels), jnp.float32)
-        want_cl = jnp.zeros((cfg.n_memory, levels), jnp.float32)
-        peeked_leaf = jnp.zeros(q.shape, bool)
-        # divergent policies scale the admission dice by the chip's share of
-        # its own measured route demand (chip-local; no collective)
-        dboost = fleet_cache.demand_boost(
-            cache_policy, cfg, demand, routing.route_linear_index(cfg, mesh)
-        )
+        with jax.named_scope("dex/lanes"):
+            n_fetch = jnp.int64(0)
+            n_hit = jnp.int64(0)
+            shed = jnp.zeros(q.shape, bool)
+            miss_cl = jnp.zeros((cfg.n_memory, levels), jnp.float32)
+            want_cl = jnp.zeros((cfg.n_memory, levels), jnp.float32)
+            peeked_leaf = jnp.zeros(q.shape, bool)
+        with jax.named_scope("dex/locate"):
+            fetchable = live & ~offl
+            local = jnp.zeros(q.shape, jnp.int32)
+            found_leaf = jnp.zeros(q.shape, bool)
+            vals_leaf = jnp.zeros(q.shape, jnp.int64)
+            rows_k_leaf = jnp.full(q.shape + (FANOUT,), KEY_MAX, jnp.int64)
+            rows_v_leaf = jnp.zeros(q.shape + (FANOUT,), jnp.int64)
+            # divergent policies scale the admission dice by the chip's
+            # share of its own measured route demand (chip-local; no
+            # collective)
+            dboost = fleet_cache.demand_boost(
+                cache_policy, cfg, demand, routing.route_linear_index(cfg, mesh)
+            )
         if do_descent:
             descent_levels = levels if do_leaf else levels - 1
             for lvl in range(descent_levels):
                 leaf_lvl = lvl == levels - 1
                 peek_elig = peek_budget = None
-                if leaf_lvl:
-                    if use_rt:
-                        # accepted lanes land directly on the predicted leaf
-                        local = jnp.where(acc, p_loc, local)
-                    want = fetchable & (
-                        (opc == OP_LOOKUP) | (opc == OP_UPDATE) | is_scan
-                    )
-                    p_ok = fleet_cache.leaf_admit(
-                        meta, cfg, cache_policy,
-                        meta.node_gid(subtree, local),
-                        stats[0, STAT_OPS] + jnp.arange(q.shape[0]),
-                        dev=dev, boost=dboost,
-                    )
-                    if may_peek:
-                        # a leaf miss whose subtree another column owns may
-                        # ask that column's cache instead of row-fetching
-                        my_col = jax.lax.axis_index(cfg.memory_axis)
-                        peek_elig = (
-                            want & (opc == OP_LOOKUP) & (col != my_col)
+                with jax.named_scope("dex/locate"):
+                    if leaf_lvl:
+                        if use_rt:
+                            # accepted lanes land directly on the predicted
+                            # leaf
+                            local = jnp.where(acc, p_loc, local)
+                        want = fetchable & (
+                            (opc == OP_LOOKUP) | (opc == OP_UPDATE) | is_scan
                         )
-                        peek_budget = fleet_cache.device_peek_budget(
-                            cache_policy, dev
+                        p_ok = fleet_cache.leaf_admit(
+                            meta, cfg, cache_policy,
+                            meta.node_gid(subtree, local),
+                            stats[0, STAT_OPS] + jnp.arange(q.shape[0]),
+                            dev=dev, boost=dboost,
                         )
-                else:
-                    # route-table-accepted lanes skip the inner fetch rounds
-                    want = fetchable & ~acc if use_rt else fetchable
-                    p_ok = jnp.ones(q.shape, bool)
-                gid = meta.node_gid(subtree, local)
+                        if may_peek:
+                            # a leaf miss whose subtree another column owns
+                            # may ask that column's cache instead of
+                            # row-fetching
+                            my_col = jax.lax.axis_index(cfg.memory_axis)
+                            peek_elig = (
+                                want & (opc == OP_LOOKUP) & (col != my_col)
+                            )
+                            peek_budget = fleet_cache.device_peek_budget(
+                                cache_policy, dev
+                            )
+                    else:
+                        # route-table-accepted lanes skip the inner fetch
+                        # rounds
+                        want = fetchable & ~acc if use_rt else fetchable
+                        p_ok = jnp.ones(q.shape, bool)
+                    gid = meta.node_gid(subtree, local)
                 with jax.named_scope(f"dex/descent/l{lvl}"):
                     rows_k, rows_c, rows_v, hit, miss, f_drop, n_msgs, \
                         new_cache, peeked = cached_fetch_level(
@@ -607,158 +663,179 @@ def make_dex_engine(
                 # served miss one remote read; peeked lanes fetch nothing
                 # here (their two-sided trip prices in the back half) and
                 # bucket-overflowed lanes got no row
-                fetched = miss & ~f_drop
-                if leaf_lvl and may_peek:
-                    fetched = fetched & ~peeked
-                cost = cost + (
-                    hit.astype(jnp.float32) * obs_latency.T_CACHED
-                    + fetched.astype(jnp.float32) * obs_latency.T_READ
-                )
-                fmiss = fmiss | fetched
-                if audit:
-                    nset = jnp.zeros((n_nodes_total,), jnp.float32).at[
-                        jnp.where(fetched & ~is_scan, gid, n_nodes_total)
-                    ].set(1.0, mode="drop")
-                    cnt_c = jnp.zeros((cfg.n_memory,), jnp.float32).at[
-                        node_col
-                    ].add(nset)
-                    a_upd = a_upd.at[1, :, lvl].add(
-                        cnt_c * float(NODE_ROW_BYTES)
+                with _lat_scope("price"):
+                    fetched = miss & ~f_drop
+                    if leaf_lvl and may_peek:
+                        fetched = fetched & ~peeked
+                    cost = cost + (
+                        hit.astype(jnp.float32) * obs_latency.T_CACHED
+                        + fetched.astype(jnp.float32) * obs_latency.T_READ
                     )
-                shed = shed | f_drop
-                n_fetch = n_fetch + n_msgs
-                n_hit = n_hit + jnp.sum(hit).astype(jnp.int64)
-                # per-(column, level) miss observation; scan lanes leave the
-                # EMA untouched (they never offload)
-                obs = (want & ~is_scan).astype(jnp.float32)
-                miss_cl = miss_cl.at[col, lvl].add(
-                    miss.astype(jnp.float32) * obs
-                )
-                want_cl = want_cl.at[col, lvl].add(obs)
-                if not leaf_lvl:
-                    cnt = jnp.sum(rows_k <= q[:, None], axis=-1)
-                    slot = jnp.maximum(cnt - 1, 0).astype(jnp.int32)
-                    local = jnp.take_along_axis(
-                        rows_c, slot[:, None], axis=-1
-                    )[:, 0]
-                else:
-                    eq = rows_k == q[:, None]
-                    found_leaf = jnp.any(eq, axis=-1) & want
-                    vals_leaf = jnp.sum(jnp.where(eq, rows_v, 0), axis=-1)
-                    rows_k_leaf, rows_v_leaf = rows_k, rows_v
-        if use_rt and not do_leaf:
-            # insert-only engines stop above the leaf; accepted lanes still
-            # land their MSG_INSERT on the predicted leaf
-            local = jnp.where(acc, p_loc, local)
-        leaf_gid = meta.node_gid(subtree, local)
+                    fmiss = fmiss | fetched
+                if audit:
+                    with _lat_scope("audit"):
+                        cnt_c = distinct_fetched_per_column(
+                            gid, fetched & ~is_scan, n_nodes_total,
+                            meta.subtree_cap, s_per, cfg.n_memory,
+                        )
+                        a_upd = a_upd.at[1, :, lvl].add(
+                            cnt_c * float(NODE_ROW_BYTES)
+                        )
+                with jax.named_scope("dex/lanes"):
+                    shed = shed | f_drop
+                    n_fetch = n_fetch + n_msgs
+                    n_hit = n_hit + jnp.sum(hit).astype(jnp.int64)
+                    # per-(column, level) miss observation; scan lanes leave
+                    # the EMA untouched (they never offload)
+                    obs = (want & ~is_scan).astype(jnp.float32)
+                    miss_cl = miss_cl.at[col, lvl].add(
+                        miss.astype(jnp.float32) * obs
+                    )
+                    want_cl = want_cl.at[col, lvl].add(obs)
+                with jax.named_scope("dex/locate"):
+                    if not leaf_lvl:
+                        cnt = jnp.sum(rows_k <= q[:, None], axis=-1)
+                        slot = jnp.maximum(cnt - 1, 0).astype(jnp.int32)
+                        local = jnp.take_along_axis(
+                            rows_c, slot[:, None], axis=-1
+                        )[:, 0]
+                    else:
+                        eq = rows_k == q[:, None]
+                        found_leaf = jnp.any(eq, axis=-1) & want
+                        vals_leaf = jnp.sum(jnp.where(eq, rows_v, 0), axis=-1)
+                        rows_k_leaf, rows_v_leaf = rows_k, rows_v
+        with jax.named_scope("dex/locate"):
+            if use_rt and not do_leaf:
+                # insert-only engines stop above the leaf; accepted lanes
+                # still land their MSG_INSERT on the predicted leaf
+                local = jnp.where(acc, p_loc, local)
+            leaf_gid = meta.node_gid(subtree, local)
 
         # --- 4. scan lanes: successor-chain sibling hops -------------------
         hop_gids = []
         hop_vers = []
         if has_scan:
-            cnt_s = jnp.clip(
-                jnp.where(is_scan, val, 0), 0, mc
-            ).astype(jnp.int32)
-            window_k = [jnp.where(is_scan[:, None], rows_k_leaf, KEY_MAX)]
-            window_v = [jnp.where(is_scan[:, None], rows_v_leaf, 0)]
-            collected = jnp.sum(
-                ((window_k[0] != KEY_MAX) & (window_k[0] >= q[:, None]))
-                .astype(jnp.int32),
-                axis=-1,
-            )
+            with jax.named_scope("dex/lanes"):
+                cnt_s = jnp.clip(
+                    jnp.where(is_scan, val, 0), 0, mc
+                ).astype(jnp.int32)
+                window_k = [jnp.where(is_scan[:, None], rows_k_leaf, KEY_MAX)]
+                window_v = [jnp.where(is_scan[:, None], rows_v_leaf, 0)]
+                collected = jnp.sum(
+                    ((window_k[0] != KEY_MAX) & (window_k[0] >= q[:, None]))
+                    .astype(jnp.int32),
+                    axis=-1,
+                )
             in_range = is_scan
             gid_h = leaf_gid
             for h in range(1, hops):
-                nxt = succ_t[jnp.where(in_range, gid_h, 0)]
-                in_range = in_range & (collected < cnt_s) & (nxt >= 0)
-                gid_h = jnp.where(in_range, nxt, gid_h)
-                gid = jnp.where(in_range, gid_h, 0)
-                if stamp:
-                    hop_gids.append(
-                        jnp.where(in_range, gid_h, -1).astype(jnp.int64)
+                with jax.named_scope("dex/locate"):
+                    nxt = succ_t[jnp.where(in_range, gid_h, 0)]
+                    in_range = in_range & (collected < cnt_s) & (nxt >= 0)
+                    gid_h = jnp.where(in_range, nxt, gid_h)
+                    gid = jnp.where(in_range, gid_h, 0)
+                    p_ok = fleet_cache.leaf_admit(
+                        meta, cfg, cache_policy, gid,
+                        stats[0, STAT_OPS] + h + jnp.arange(q.shape[0]),
+                        dev=dev, boost=dboost,
                     )
-                    hop_vers.append(jnp.where(in_range, vers[gid], 0))
-                p_ok = fleet_cache.leaf_admit(
-                    meta, cfg, cache_policy, gid,
-                    stats[0, STAT_OPS] + h + jnp.arange(q.shape[0]),
-                    dev=dev, boost=dboost,
-                )
+                if stamp:
+                    with jax.named_scope("dex/coherence"):
+                        hop_gids.append(
+                            jnp.where(in_range, gid_h, -1).astype(jnp.int64)
+                        )
+                        hop_vers.append(jnp.where(in_range, vers[gid], 0))
                 with jax.named_scope(f"dex/scan/h{h}"):
                     rows_k, _rows_c, rows_v, hit, miss, f_drop, n_msgs, \
                         new_cache, _peeked = cached_fetch_level(
                             pool, meta, cfg, new_cache, vers, gid, in_range,
                             p_ok,
                         )
-                shed = shed | f_drop
-                n_fetch = n_fetch + n_msgs
-                n_hit = n_hit + jnp.sum(hit).astype(jnp.int64)
                 # ledger: each executed hop prices like one more leaf level
                 # plus the per-hop local search the simulator books
-                fetched_h = miss & ~f_drop
-                cost = cost + (
-                    hit.astype(jnp.float32) * obs_latency.T_CACHED
-                    + fetched_h.astype(jnp.float32) * obs_latency.T_READ
-                    + in_range.astype(jnp.float32) * obs_latency.T_LOCAL
-                )
-                fmiss = fmiss | fetched_h
-                rows_k = jnp.where(in_range[:, None], rows_k, KEY_MAX)
-                rows_v = jnp.where(in_range[:, None], rows_v, 0)
-                collected = collected + jnp.sum(
-                    ((rows_k != KEY_MAX) & (rows_k >= q[:, None]))
-                    .astype(jnp.int32),
-                    axis=-1,
-                )
+                with _lat_scope("price"):
+                    fetched_h = miss & ~f_drop
+                    cost = cost + (
+                        hit.astype(jnp.float32) * obs_latency.T_CACHED
+                        + fetched_h.astype(jnp.float32) * obs_latency.T_READ
+                        + in_range.astype(jnp.float32) * obs_latency.T_LOCAL
+                    )
+                    fmiss = fmiss | fetched_h
+                with jax.named_scope("dex/lanes"):
+                    shed = shed | f_drop
+                    n_fetch = n_fetch + n_msgs
+                    n_hit = n_hit + jnp.sum(hit).astype(jnp.int64)
+                    rows_k = jnp.where(in_range[:, None], rows_k, KEY_MAX)
+                    rows_v = jnp.where(in_range[:, None], rows_v, 0)
+                    collected = collected + jnp.sum(
+                        ((rows_k != KEY_MAX) & (rows_k >= q[:, None]))
+                        .astype(jnp.int32),
+                        axis=-1,
+                    )
                 window_k.append(rows_k)
                 window_v.append(rows_v)
-            wk = jnp.concatenate(window_k, axis=-1)
-            wv = jnp.concatenate(window_v, axis=-1)
-            if use_kernel:
-                sc_k, sc_v, taken = leaf_scan(
-                    wk, wv, q, cnt_s, max_count=mc, interpret=interpret
-                )
-            else:
-                sc_k, sc_v, taken = leaf_scan_ref(wk, wv, q, cnt_s, max_count=mc)
-            ok_scan = is_scan & ~shed
-            sc_k = jnp.where(ok_scan[:, None], sc_k, KEY_MAX)
-            sc_v = jnp.where(ok_scan[:, None], sc_v, 0)
-            taken = jnp.where(
-                ok_scan, taken, jnp.where(is_scan & shed, -1, 0)
-            ).astype(jnp.int32)
+            # the window's assembly, the kernel's operand padding and int64
+            # split/join, and the answer masks; the leaf_scan kernel itself
+            # is found by its name, not its scope
+            with jax.named_scope("dex/lanes"):
+                wk = jnp.concatenate(window_k, axis=-1)
+                wv = jnp.concatenate(window_v, axis=-1)
+                if use_kernel:
+                    sc_k, sc_v, taken = leaf_scan(
+                        wk, wv, q, cnt_s, max_count=mc, interpret=interpret
+                    )
+                else:
+                    sc_k, sc_v, taken = leaf_scan_ref(
+                        wk, wv, q, cnt_s, max_count=mc
+                    )
+                ok_scan = is_scan & ~shed
+                sc_k = jnp.where(ok_scan[:, None], sc_k, KEY_MAX)
+                sc_v = jnp.where(ok_scan[:, None], sc_v, 0)
+                taken = jnp.where(
+                    ok_scan, taken, jnp.where(is_scan & shed, -1, 0)
+                ).astype(jnp.int32)
 
         # ledger: compute-side leaf search — lookups that stayed one-sided,
         # plus a scan's first (descent) hop
-        if has_lookup:
-            cost = cost + (
-                live & (opc == OP_LOOKUP) & ~offl
-            ).astype(jnp.float32) * obs_latency.T_LOCAL
-        if has_scan:
-            cost = cost + is_scan.astype(jnp.float32) * obs_latency.T_LOCAL
+        with _lat_scope("price"):
+            if has_lookup:
+                cost = cost + (
+                    live & (opc == OP_LOOKUP) & ~offl
+                ).astype(jnp.float32) * obs_latency.T_LOCAL
+            if has_scan:
+                cost = cost + is_scan.astype(jnp.float32) * obs_latency.T_LOCAL
 
         # --- front-half EMA + stats ----------------------------------------
-        g_miss = jax.lax.psum(miss_cl, cfg.all_axes)
-        g_want = jax.lax.psum(want_cl, cfg.all_axes)
-        rates = g_miss / jnp.maximum(g_want, 1.0)
-        new_ema = jnp.where(
-            g_want[None, :, :] > 0,
-            cfg.ema_decay * miss_ema + (1 - cfg.ema_decay) * rates[None, :, :],
-            miss_ema,
-        )
-        f_upd = jnp.zeros((1, N_STATS), jnp.int64)
-        f_upd = f_upd.at[0, STAT_OPS].set(jnp.sum(live).astype(jnp.int64))
-        f_upd = f_upd.at[0, STAT_HITS].set(n_hit)
-        f_upd = f_upd.at[0, STAT_FETCHES].set(n_fetch)
-        f_upd = f_upd.at[0, STAT_DROPS].set(
-            jnp.sum(dropped_r).astype(jnp.int64)
-        )
-        if has_offloadable:
-            # group decisions are mesh-global: count them once, on the
-            # first device
-            first = (dev == 0).astype(jnp.int64)
-            f_upd = f_upd.at[0, STAT_OFFLOAD_GROUPS].set(first * n_off_groups)
-            f_upd = f_upd.at[0, STAT_FETCH_GROUPS].set(first * n_fetch_groups)
-        if use_rt:
-            f_upd = f_upd.at[0, STAT_RT_SKIPS].set(n_rt_skips)
-            f_upd = f_upd.at[0, STAT_RT_MISPREDICTS].set(n_rt_mis)
+        with jax.named_scope("dex/lanes"):
+            g_miss = jax.lax.psum(miss_cl, cfg.all_axes)
+            g_want = jax.lax.psum(want_cl, cfg.all_axes)
+            rates = g_miss / jnp.maximum(g_want, 1.0)
+            new_ema = jnp.where(
+                g_want[None, :, :] > 0,
+                cfg.ema_decay * miss_ema
+                + (1 - cfg.ema_decay) * rates[None, :, :],
+                miss_ema,
+            )
+            f_upd = jnp.zeros((1, N_STATS), jnp.int64)
+            f_upd = f_upd.at[0, STAT_OPS].set(jnp.sum(live).astype(jnp.int64))
+            f_upd = f_upd.at[0, STAT_HITS].set(n_hit)
+            f_upd = f_upd.at[0, STAT_FETCHES].set(n_fetch)
+            f_upd = f_upd.at[0, STAT_DROPS].set(
+                jnp.sum(dropped_r).astype(jnp.int64)
+            )
+            if has_offloadable:
+                # group decisions are mesh-global: count them once, on the
+                # first device
+                first = (dev == 0).astype(jnp.int64)
+                f_upd = f_upd.at[0, STAT_OFFLOAD_GROUPS].set(
+                    first * n_off_groups
+                )
+                f_upd = f_upd.at[0, STAT_FETCH_GROUPS].set(
+                    first * n_fetch_groups
+                )
+            if use_rt:
+                f_upd = f_upd.at[0, STAT_RT_SKIPS].set(n_rt_skips)
+                f_upd = f_upd.at[0, STAT_RT_MISPREDICTS].set(n_rt_mis)
 
         carry = {
             "q": q, "val": val, "opc": opc, "pr": pr, "subtree": subtree,
@@ -768,16 +845,17 @@ def make_dex_engine(
         }
         if may_peek:
             carry["peek"] = peeked_leaf
-        if stamp:
-            gsafe = jnp.clip(leaf_gid, 0, n_nodes_total - 1)
-            carry["vseen"] = jnp.where(live, vers[gsafe], 0)
         if has_scan:
             carry.update(sck=sc_k, scv=sc_v, taken=taken)
-            if stamp:
-                if hop_gids:
+        if stamp:
+            # version stamps for the back half's overlap-window check
+            with jax.named_scope("dex/coherence"):
+                gsafe = jnp.clip(leaf_gid, 0, n_nodes_total - 1)
+                carry["vseen"] = jnp.where(live, vers[gsafe], 0)
+                if has_scan and hop_gids:
                     carry["hgid"] = jnp.stack(hop_gids, axis=-1)
                     carry["hver"] = jnp.stack(hop_vers, axis=-1)
-                else:
+                elif has_scan:
                     carry["hgid"] = jnp.full(q.shape + (0,), -1, jnp.int64)
                     carry["hver"] = jnp.zeros(q.shape + (0,), vers.dtype)
         return carry, new_cache, new_ema, new_demand, f_upd, a_upd
@@ -788,7 +866,8 @@ def make_dex_engine(
         apply, version bumps + cache write-through, and the reverse route
         exchange returning per-lane results."""
         n_route = cfg.n_route
-        vers = versions[0]
+        with jax.named_scope("dex/lanes"):
+            vers = versions[0]
         n_nodes_total = vers.shape[0]
         q = carry["q"]
         val = carry["val"]
@@ -806,201 +885,217 @@ def make_dex_engine(
         fmiss = carry["fmiss"]
         peek_c = carry["peek"] if may_peek else None
         cap = lane.shape[1]
-        live = q != KEY_MAX
-        is_scan = live & (opc == OP_SCAN) if has_scan else jnp.zeros(q.shape, bool)
-        col = (subtree // s_per).astype(jnp.int32)
+        with jax.named_scope("dex/lanes"):
+            live = q != KEY_MAX
+            is_scan = (
+                live & (opc == OP_SCAN) if has_scan
+                else jnp.zeros(q.shape, bool)
+            )
+        with jax.named_scope("dex/locate"):
+            col = (subtree // s_per).astype(jnp.int32)
         if has_scan:
             sc_k, sc_v, taken = carry["sck"], carry["scv"], carry["taken"]
 
         # --- overlap-window stale check (pipeline back half only) ----------
-        n_stalls = jnp.int64(0)
-        stalled = jnp.zeros(q.shape, bool)
+        with jax.named_scope("dex/coherence"):
+            n_stalls = jnp.int64(0)
+            stalled = jnp.zeros(q.shape, bool)
         if check_stale:
-            gsafe = jnp.clip(leaf_gid, 0, n_nodes_total - 1)
-            stale = live & (vers[gsafe] != carry["vseen"])
-            # lookups/updates whose leaf the overlapped batch wrote re-run
-            # two-sided against the authoritative post-overlap pool; inserts
-            # never need forcing (the apply re-searches the leaf); already
-            # -offloaded lanes are authoritative as-is
-            force_off = (
-                stale & ~offl & ~shed & ~is_scan
-                & ((opc == OP_LOOKUP) | (opc == OP_UPDATE))
-            ) if (has_lookup or has_update) else jnp.zeros(q.shape, bool)
-            n_stalls = n_stalls + jnp.sum(force_off).astype(jnp.int64)
-            if has_scan:
-                # conservative conflict stall: a scan whose window crossed
-                # any written leaf sheds to the retry lane
-                hg, hv = carry["hgid"], carry["hver"]
-                hvalid = hg >= 0
-                hsafe = jnp.clip(hg, 0, n_nodes_total - 1)
-                hstale = jnp.any(hvalid & (vers[hsafe] != hv), axis=-1)
-                sc_stale = is_scan & ~shed & (stale | hstale)
-                n_stalls = n_stalls + jnp.sum(sc_stale).astype(jnp.int64)
-                sc_k = jnp.where(sc_stale[:, None], KEY_MAX, sc_k)
-                sc_v = jnp.where(sc_stale[:, None], 0, sc_v)
-                taken = jnp.where(sc_stale, -1, taken).astype(jnp.int32)
-                shed = shed | sc_stale
-                stalled = stalled | sc_stale
-            stalled = stalled | force_off
-            with (
-                jax.named_scope("dex/lat/stale_forced"),
-                routing.trace_phase("dex/lat"),
-            ):
+            with jax.named_scope("dex/coherence"):
+                gsafe = jnp.clip(leaf_gid, 0, n_nodes_total - 1)
+                stale = live & (vers[gsafe] != carry["vseen"])
+                # lookups/updates whose leaf the overlapped batch wrote
+                # re-run two-sided against the authoritative post-overlap
+                # pool; inserts never need forcing (the apply re-searches the
+                # leaf); already-offloaded lanes are authoritative as-is
+                force_off = (
+                    stale & ~offl & ~shed & ~is_scan
+                    & ((opc == OP_LOOKUP) | (opc == OP_UPDATE))
+                ) if (has_lookup or has_update) else jnp.zeros(q.shape, bool)
+                n_stalls = n_stalls + jnp.sum(force_off).astype(jnp.int64)
+                if has_scan:
+                    # conservative conflict stall: a scan whose window
+                    # crossed any written leaf sheds to the retry lane
+                    hg, hv = carry["hgid"], carry["hver"]
+                    hvalid = hg >= 0
+                    hsafe = jnp.clip(hg, 0, n_nodes_total - 1)
+                    hstale = jnp.any(hvalid & (vers[hsafe] != hv), axis=-1)
+                    sc_stale = is_scan & ~shed & (stale | hstale)
+                    n_stalls = n_stalls + jnp.sum(sc_stale).astype(jnp.int64)
+                    sc_k = jnp.where(sc_stale[:, None], KEY_MAX, sc_k)
+                    sc_v = jnp.where(sc_stale[:, None], 0, sc_v)
+                    taken = jnp.where(sc_stale, -1, taken).astype(jnp.int32)
+                    shed = shed | sc_stale
+                    stalled = stalled | sc_stale
+                stalled = stalled | force_off
+                offl_eff = offl | force_off
+            with _lat_scope("stale_forced"):
                 # a stale-caught lane re-resolves two-sided at the leaf: the
                 # simulator's stall site prices one RPC plus a single-level
                 # memory-side walk (``_offload(server, leaf, 1)``)
                 cost = cost + stalled.astype(jnp.float32) * (
                     obs_latency.T_RPC + obs_latency.T_MEM
                 )
-            offl_eff = offl | force_off
         else:
             offl_eff = offl
 
         # --- 5. ONE fused tagged request/response all_to_all pair ----------
-        rstat = jnp.zeros(q.shape, jnp.int32)
-        rval = jnp.zeros(q.shape, jnp.int64)
-        rgid = jnp.full(q.shape, KEY_MAX, jnp.int64)
-        rrow_v = jnp.zeros(q.shape + (FANOUT,), jnp.int64)
-        send = jnp.zeros(q.shape, bool)
-        dropped_w = jnp.zeros(q.shape, bool)
-        sent_peek = jnp.zeros(q.shape, bool)
-        n_off_msgs = jnp.int64(0)
-        n_write_msgs = jnp.int64(0)
-        n_peer_hits = jnp.int64(0)
-        n_peer_misses = jnp.int64(0)
+        with jax.named_scope("dex/lanes"):
+            rstat = jnp.zeros(q.shape, jnp.int32)
+            rval = jnp.zeros(q.shape, jnp.int64)
+            rgid = jnp.full(q.shape, KEY_MAX, jnp.int64)
+            rrow_v = jnp.zeros(q.shape + (FANOUT,), jnp.int64)
+            send = jnp.zeros(q.shape, bool)
+            dropped_w = jnp.zeros(q.shape, bool)
+            sent_peek = jnp.zeros(q.shape, bool)
+            n_off_msgs = jnp.int64(0)
+            n_write_msgs = jnp.int64(0)
+            n_peer_hits = jnp.int64(0)
+            n_peer_misses = jnp.int64(0)
         new_pk, new_pv, new_occ = (
             pool.pool_keys, pool.pool_values, occupancy
         )
         new_cache = cache
         if do_fused:
-            tag = jnp.zeros(q.shape, jnp.int64)
-            ok_lane = live & ~shed
-            if has_lookup and may_offload:
-                tag = jnp.where(
-                    ok_lane & (opc == OP_LOOKUP) & offl_eff, MSG_OFF_LOOKUP,
-                    tag,
-                )
-            if may_peek:
-                # a peeked leaf miss resolves two-sided at the owning column
-                # (a stale-forced lane keeps its MSG_OFF_LOOKUP instead)
-                tag = jnp.where(
-                    ok_lane & (opc == OP_LOOKUP) & peek_c & ~offl_eff,
-                    MSG_PEEK, tag,
-                )
-            if has_update:
-                if may_offload:
+            with jax.named_scope("dex/lanes"):
+                tag = jnp.zeros(q.shape, jnp.int64)
+                ok_lane = live & ~shed
+                if has_lookup and may_offload:
                     tag = jnp.where(
-                        ok_lane & (opc == OP_UPDATE) & offl_eff,
-                        MSG_OFF_UPDATE, tag,
+                        ok_lane & (opc == OP_LOOKUP) & offl_eff,
+                        MSG_OFF_LOOKUP, tag,
                     )
-                tag = jnp.where(
-                    ok_lane & (opc == OP_UPDATE) & ~offl_eff & found_leaf,
-                    MSG_UPDATE, tag,
-                )
-            if has_insert:
-                if may_offload:
+                if may_peek:
+                    # a peeked leaf miss resolves two-sided at the owning
+                    # column (a stale-forced lane keeps its MSG_OFF_LOOKUP
+                    # instead)
                     tag = jnp.where(
-                        ok_lane & (opc == OP_INSERT) & offl_eff,
-                        MSG_OFF_INSERT, tag,
+                        ok_lane & (opc == OP_LOOKUP) & peek_c & ~offl_eff,
+                        MSG_PEEK, tag,
                     )
-                tag = jnp.where(
-                    ok_lane & (opc == OP_INSERT) & ~offl_eff, MSG_INSERT, tag
+                if has_update:
+                    if may_offload:
+                        tag = jnp.where(
+                            ok_lane & (opc == OP_UPDATE) & offl_eff,
+                            MSG_OFF_UPDATE, tag,
+                        )
+                    tag = jnp.where(
+                        ok_lane & (opc == OP_UPDATE) & ~offl_eff & found_leaf,
+                        MSG_UPDATE, tag,
+                    )
+                if has_insert:
+                    if may_offload:
+                        tag = jnp.where(
+                            ok_lane & (opc == OP_INSERT) & offl_eff,
+                            MSG_OFF_INSERT, tag,
+                        )
+                    tag = jnp.where(
+                        ok_lane & (opc == OP_INSERT) & ~offl_eff, MSG_INSERT,
+                        tag,
+                    )
+                if may_peek:
+                    sent_peek = tag == MSG_PEEK
+                send = tag != MSG_NONE
+                dest = jnp.where(send, col, cfg.n_memory)
+                wcap = routing.route_capacity(
+                    q.shape[0], cfg.n_memory, cfg.route_capacity_factor
                 )
-            if may_peek:
-                sent_peek = tag == MSG_PEEK
-            send = tag != MSG_NONE
-            dest = jnp.where(send, col, cfg.n_memory)
-            wcap = routing.route_capacity(
-                q.shape[0], cfg.n_memory, cfg.route_capacity_factor
-            )
-            wpayload = jnp.stack(
-                [
-                    tag,
-                    jnp.where(
-                        (tag == MSG_UPDATE) | (tag == MSG_INSERT)
-                        | (tag == MSG_PEEK),
-                        leaf_gid, KEY_MAX,
-                    ),
-                    subtree.astype(jnp.int64),
-                    q,
-                    val,
-                    pr,
-                ],
-                axis=-1,
-            )                                               # [Q, REQ_FIELDS]
-            wbuf, wlane, dropped_w = routing.pack_by_dest(
-                wpayload, dest, cfg.n_memory, wcap
-            )
-            dropped_w = dropped_w & send
+                wpayload = jnp.stack(
+                    [
+                        tag,
+                        jnp.where(
+                            (tag == MSG_UPDATE) | (tag == MSG_INSERT)
+                            | (tag == MSG_PEEK),
+                            leaf_gid, KEY_MAX,
+                        ),
+                        subtree.astype(jnp.int64),
+                        q,
+                        val,
+                        pr,
+                    ],
+                    axis=-1,
+                )                                           # [Q, REQ_FIELDS]
+                wbuf, wlane, dropped_w = routing.pack_by_dest(
+                    wpayload, dest, cfg.n_memory, wcap
+                )
+                dropped_w = dropped_w & send
             with jax.named_scope("dex/fused_a2a/request"):
                 req = routing.a2a(wbuf, cfg.memory_axis)  # [n_mem, wcap, RF]
-            if has_writes:
-                # every route-replica of this memory column must apply the
-                # identical write batch (pool replicas stay consistent)
-                req = routing.gather_route(req, cfg)     # [R, n_mem, wcap, RF]
-            flat = req.reshape(-1, REQ_FIELDS)
-            tagf = flat[:, 0]
-            gidf = flat[:, 1]
-            stf = flat[:, 2]
-            kf = flat[:, 3]
-            vf = flat[:, 4]
-            prf = flat[:, 5]
-            wgid = jnp.where(
-                (tagf == MSG_UPDATE) | (tagf == MSG_INSERT), gidf, KEY_MAX
-            )
-            resp_val = jnp.zeros(kf.shape, jnp.int64)
-            o_found = jnp.zeros(kf.shape, bool)
-            peekf = jnp.zeros(kf.shape, bool)
+            with jax.named_scope("dex/lanes"):
+                if has_writes:
+                    # every route-replica of this memory column must apply
+                    # the identical write batch (pool replicas stay
+                    # consistent)
+                    req = routing.gather_route(req, cfg)  # [R, n_mem, wcap, RF]
+                flat = req.reshape(-1, REQ_FIELDS)
+                tagf = flat[:, 0]
+                gidf = flat[:, 1]
+                stf = flat[:, 2]
+                kf = flat[:, 3]
+                vf = flat[:, 4]
+                prf = flat[:, 5]
+                wgid = jnp.where(
+                    (tagf == MSG_UPDATE) | (tagf == MSG_INSERT), gidf, KEY_MAX
+                )
+                resp_val = jnp.zeros(kf.shape, jnp.int64)
+                o_found = jnp.zeros(kf.shape, bool)
+                peekf = jnp.zeros(kf.shape, bool)
             if may_offload or may_peek:
-                offf = (
-                    (tagf >= MSG_OFF_LOOKUP) & (tagf <= MSG_OFF_INSERT)
-                    if may_offload else jnp.zeros(kf.shape, bool)
-                )
-                if may_peek:
-                    peekf = tagf == MSG_PEEK
-                walkf = offf | peekf
-                # owner-side block walk for offloaded (and peer-missed
-                # peeked) lanes (§6): the whole remaining traversal runs
-                # next to the data
-                stl = jnp.where(walkf, stf % s_per, 0).astype(jnp.int32)
-                loc = jnp.zeros(kf.shape, jnp.int32)
-                for _ in range(levels - 1):
-                    rows = node_rows(pool.pool_keys, stl, loc)
-                    cnt = jnp.sum(rows <= kf[:, None], axis=-1)
-                    slot = jnp.maximum(cnt - 1, 0).astype(jnp.int32)
-                    loc = pool.pool_children[stl, loc * FANOUT + slot]
-                o_rows_k = node_rows(pool.pool_keys, stl, loc)
-                o_eq = o_rows_k == kf[:, None]
-                o_found = jnp.any(o_eq, axis=-1) & walkf
-                o_val = jnp.sum(
-                    jnp.where(o_eq, node_rows(pool.pool_values, stl, loc), 0),
-                    axis=-1,
-                )
-                if may_offload:
-                    gid_eff = meta.node_gid(stf, loc.astype(jnp.int64))
-                    wgid = jnp.where(
-                        (tagf == MSG_OFF_UPDATE) | (tagf == MSG_OFF_INSERT),
-                        gid_eff, wgid,
+                with jax.named_scope("dex/offload"):
+                    offf = (
+                        (tagf >= MSG_OFF_LOOKUP) & (tagf <= MSG_OFF_INSERT)
+                        if may_offload else jnp.zeros(kf.shape, bool)
                     )
-                peer_hit = jnp.zeros(kf.shape, bool)
-                if may_peek:
-                    # sibling-cache overlay: if this chip's own cache holds a
-                    # version-fresh copy of the peeked leaf, answer from it —
-                    # a stale or absent row falls back to the walk above
-                    peer_hit, p_found, p_val = fleet_cache.peer_answer(
-                        cache, cfg, vers, gidf, kf, peekf
+                    if may_peek:
+                        peekf = tagf == MSG_PEEK
+                    walkf = offf | peekf
+                    # owner-side block walk for offloaded (and peer-missed
+                    # peeked) lanes (§6): the whole remaining traversal runs
+                    # next to the data
+                    stl = jnp.where(walkf, stf % s_per, 0).astype(jnp.int32)
+                    loc = jnp.zeros(kf.shape, jnp.int32)
+                    for _ in range(levels - 1):
+                        rows = node_rows(pool.pool_keys, stl, loc)
+                        cnt = jnp.sum(rows <= kf[:, None], axis=-1)
+                        slot = jnp.maximum(cnt - 1, 0).astype(jnp.int32)
+                        loc = pool.pool_children[stl, loc * FANOUT + slot]
+                    o_rows_k = node_rows(pool.pool_keys, stl, loc)
+                    o_eq = o_rows_k == kf[:, None]
+                    o_found = jnp.any(o_eq, axis=-1) & walkf
+                    o_val = jnp.sum(
+                        jnp.where(
+                            o_eq, node_rows(pool.pool_values, stl, loc), 0
+                        ),
+                        axis=-1,
                     )
-                    o_found = jnp.where(peer_hit, p_found, o_found)
-                    o_val = jnp.where(peer_hit, p_val, o_val)
-                lk_tags = (
-                    (tagf == MSG_OFF_LOOKUP) | peekf
-                    if may_offload else peekf
-                )
-                resp_val = jnp.where(lk_tags, o_val, 0)
+                    if may_offload:
+                        gid_eff = meta.node_gid(stf, loc.astype(jnp.int64))
+                        wgid = jnp.where(
+                            (tagf == MSG_OFF_UPDATE)
+                            | (tagf == MSG_OFF_INSERT),
+                            gid_eff, wgid,
+                        )
+                    peer_hit = jnp.zeros(kf.shape, bool)
+                    if may_peek:
+                        # sibling-cache overlay: if this chip's own cache
+                        # holds a version-fresh copy of the peeked leaf,
+                        # answer from it — a stale or absent row falls back
+                        # to the walk above
+                        peer_hit, p_found, p_val = fleet_cache.peer_answer(
+                            cache, cfg, vers, gidf, kf, peekf
+                        )
+                        o_found = jnp.where(peer_hit, p_found, o_found)
+                        o_val = jnp.where(peer_hit, p_val, o_val)
+                    lk_tags = (
+                        (tagf == MSG_OFF_LOOKUP) | peekf
+                        if may_offload else peekf
+                    )
+                    resp_val = jnp.where(lk_tags, o_val, 0)
             if has_writes:
-                allow_ins = tagf == MSG_INSERT
-                if may_offload:
-                    allow_ins = allow_ins | (tagf == MSG_OFF_INSERT)
+                with jax.named_scope("dex/lanes"):
+                    allow_ins = tagf == MSG_INSERT
+                    if may_offload:
+                        allow_ins = allow_ins | (tagf == MSG_OFF_INSERT)
                 with jax.named_scope("dex/apply"):
                     (new_pk, new_pv, new_occ, wstat, rows_v_all,
                      ins_in_leaf) = _apply_leaf_writes(
@@ -1008,143 +1103,153 @@ def make_dex_engine(
                         cfg, wgid, kf, vf, prf, allow_ins,
                         use_kernel=use_kernel, interpret=interpret,
                     )
-            else:
-                wstat = jnp.zeros(kf.shape, jnp.int32)
-                rows_v_all = jnp.zeros(kf.shape + (FANOUT,), jnp.int64)
-                ins_in_leaf = jnp.zeros(kf.shape, bool)
-            if may_offload or may_peek:
-                wstat = jnp.where(
-                    lk_tags,
-                    jnp.where(o_found, STATUS_OK, STATUS_MISS),
-                    wstat,
+            with jax.named_scope("dex/lanes"):
+                if not has_writes:
+                    wstat = jnp.zeros(kf.shape, jnp.int32)
+                    rows_v_all = jnp.zeros(kf.shape + (FANOUT,), jnp.int64)
+                    ins_in_leaf = jnp.zeros(kf.shape, bool)
+                if may_offload or may_peek:
+                    wstat = jnp.where(
+                        lk_tags,
+                        jnp.where(o_found, STATUS_OK, STATUS_MISS),
+                        wstat,
+                    )
+                # field 3 doubles as the peer-cache-hit bit for MSG_PEEK
+                # lanes (they are lookups, so the insert-path consumers never
+                # read it)
+                ins_flag = (
+                    jnp.where(peekf, peer_hit, ins_in_leaf)
+                    if may_peek else ins_in_leaf
                 )
-            # field 3 doubles as the peer-cache-hit bit for MSG_PEEK lanes
-            # (they are lookups, so the insert-path consumers never read it)
-            ins_flag = (
-                jnp.where(peekf, peer_hit, ins_in_leaf)
-                if may_peek else ins_in_leaf
-            )
-            resp = jnp.concatenate(
-                [
-                    wstat[:, None].astype(jnp.int64),
-                    resp_val[:, None],
-                    wgid[:, None],
-                    ins_flag[:, None].astype(jnp.int64),
-                    rows_v_all,
-                ],
-                axis=-1,
-            )
-            if has_writes:
-                # respond only to this device's own route row
-                r_lin = routing.route_linear_index(cfg, mesh)
-                resp = jnp.take(
-                    resp.reshape(
-                        cfg.n_route, cfg.n_memory, wcap, RESP_HEAD + FANOUT
-                    ),
-                    r_lin, axis=0,
+                resp = jnp.concatenate(
+                    [
+                        wstat[:, None].astype(jnp.int64),
+                        resp_val[:, None],
+                        wgid[:, None],
+                        ins_flag[:, None].astype(jnp.int64),
+                        rows_v_all,
+                    ],
+                    axis=-1,
                 )
-            else:
-                resp = resp.reshape(cfg.n_memory, wcap, RESP_HEAD + FANOUT)
+                if has_writes:
+                    # respond only to this device's own route row
+                    r_lin = routing.route_linear_index(cfg, mesh)
+                    resp = jnp.take(
+                        resp.reshape(
+                            cfg.n_route, cfg.n_memory, wcap,
+                            RESP_HEAD + FANOUT,
+                        ),
+                        r_lin, axis=0,
+                    )
+                else:
+                    resp = resp.reshape(cfg.n_memory, wcap, RESP_HEAD + FANOUT)
             with jax.named_scope("dex/fused_a2a/response"):
                 resp = routing.a2a(resp, cfg.memory_axis)
-            back = routing.unpack_to_lanes(resp, wlane, q.shape[0], 0)
-            rstat = back[..., 0].astype(jnp.int32)
-            rval = back[..., 1]
-            rgid = back[..., 2]
-            r_ins = back[..., 3] != 0
-            rrow_v = back[..., RESP_HEAD:]
-            delivered = send & ~dropped_w
-            is_off_lane = offl_eff & send
-            n_off_msgs = jnp.sum(delivered & is_off_lane).astype(jnp.int64)
-            n_write_msgs = jnp.sum(
-                delivered & ~is_off_lane & (opc != OP_LOOKUP)
-            ).astype(jnp.int64)
-            if may_peek:
-                n_peer_hits = jnp.sum(
-                    delivered & sent_peek & r_ins
+            with jax.named_scope("dex/lanes"):
+                back = routing.unpack_to_lanes(resp, wlane, q.shape[0], 0)
+                rstat = back[..., 0].astype(jnp.int32)
+                rval = back[..., 1]
+                rgid = back[..., 2]
+                r_ins = back[..., 3] != 0
+                rrow_v = back[..., RESP_HEAD:]
+                delivered = send & ~dropped_w
+                is_off_lane = offl_eff & send
+                n_off_msgs = jnp.sum(delivered & is_off_lane).astype(jnp.int64)
+                n_write_msgs = jnp.sum(
+                    delivered & ~is_off_lane & (opc != OP_LOOKUP)
                 ).astype(jnp.int64)
-                n_peer_misses = jnp.sum(
-                    delivered & sent_peek & ~r_ins
-                ).astype(jnp.int64)
+                if may_peek:
+                    n_peer_hits = jnp.sum(
+                        delivered & sent_peek & r_ins
+                    ).astype(jnp.int64)
+                    n_peer_misses = jnp.sum(
+                        delivered & sent_peek & ~r_ins
+                    ).astype(jnp.int64)
 
         # --- 6. write-through-and-invalidate + version bump ----------------
         new_versions = versions
         if has_writes:
-            delivered = send & ~dropped_w
-            wrote_ok = (
-                delivered
-                & ((opc == OP_UPDATE) | (opc == OP_INSERT))
-                & (rstat == STATUS_OK)
-            )
-            gsafe0 = jnp.where(wrote_ok, rgid, 0)
-            nv = vers[gsafe0] + 1
-            gsafe = jnp.where(wrote_ok, rgid, n_nodes_total)
-            vers2 = vers.at[gsafe].max(nv, mode="drop")
-            new_versions = jax.lax.pmax(vers2[None, :], cfg.all_axes)
-            set_idx = (
-                routing.hash64(rgid) % jnp.uint64(cfg.cache_sets)
-            ).astype(jnp.int32)
-            eqt = new_cache.tags[0, set_idx] == rgid[:, None]
-            chit = jnp.any(eqt, axis=-1) & wrote_ok
-            way = jnp.argmax(eqt, axis=-1).astype(jnp.int32)
-            if has_update:
-                # refresh the chip's own cached row with the authoritative
-                # post-batch values, stamped with the bumped version — but
-                # NOT when the leaf also took same-batch inserts (possibly
-                # from another chip): the cached keys plane would be stale
-                # under a current version stamp; leaving the old stamp makes
-                # the version check refetch the whole row instead
-                u_hit = chit & (opc == OP_UPDATE) & ~r_ins
-                if check_stale:
-                    # a stale-forced update resolved two-sided against a
-                    # leaf the overlapped batch moved: the chip's cached
-                    # keys plane is one batch behind the response's value
-                    # row, so an in-place refresh would stitch a misaligned
-                    # pair under a current version stamp.  Leave the old
-                    # stamp; the bumped version forces a clean refetch.
-                    u_hit = u_hit & ~force_off
-                sidx = jnp.where(u_hit, set_idx, cfg.cache_sets)
-                cvals = new_cache.values.at[0, sidx, way].set(
-                    rrow_v, mode="drop"
+            with jax.named_scope("dex/coherence"):
+                delivered = send & ~dropped_w
+                wrote_ok = (
+                    delivered
+                    & ((opc == OP_UPDATE) | (opc == OP_INSERT))
+                    & (rstat == STATUS_OK)
                 )
-                cver = new_cache.ver.at[0, sidx, way].set(
-                    jnp.where(u_hit, nv, 0), mode="drop"
-                )
-                new_cache = new_cache._replace(values=cvals, ver=cver)
-            if has_insert:
-                # drop the chip's own (now key-shifted) cached row
-                i_hit = chit & (opc == OP_INSERT)
-                sidx = jnp.where(i_hit, set_idx, cfg.cache_sets)
-                ctags = new_cache.tags.at[0, sidx, way].set(-1, mode="drop")
-                new_cache = new_cache._replace(tags=ctags)
+                gsafe0 = jnp.where(wrote_ok, rgid, 0)
+                nv = vers[gsafe0] + 1
+                gsafe = jnp.where(wrote_ok, rgid, n_nodes_total)
+                vers2 = vers.at[gsafe].max(nv, mode="drop")
+                new_versions = jax.lax.pmax(vers2[None, :], cfg.all_axes)
+                set_idx = (
+                    routing.hash64(rgid) % jnp.uint64(cfg.cache_sets)
+                ).astype(jnp.int32)
+                eqt = new_cache.tags[0, set_idx] == rgid[:, None]
+                chit = jnp.any(eqt, axis=-1) & wrote_ok
+                way = jnp.argmax(eqt, axis=-1).astype(jnp.int32)
+                if has_update:
+                    # refresh the chip's own cached row with the
+                    # authoritative post-batch values, stamped with the
+                    # bumped version — but NOT when the leaf also took
+                    # same-batch inserts (possibly from another chip): the
+                    # cached keys plane would be stale under a current
+                    # version stamp; leaving the old stamp makes the version
+                    # check refetch the whole row instead
+                    u_hit = chit & (opc == OP_UPDATE) & ~r_ins
+                    if check_stale:
+                        # a stale-forced update resolved two-sided against a
+                        # leaf the overlapped batch moved: the chip's cached
+                        # keys plane is one batch behind the response's
+                        # value row, so an in-place refresh would stitch a
+                        # misaligned pair under a current version stamp.
+                        # Leave the old stamp; the bumped version forces a
+                        # clean refetch.
+                        u_hit = u_hit & ~force_off
+                    sidx = jnp.where(u_hit, set_idx, cfg.cache_sets)
+                    cvals = new_cache.values.at[0, sidx, way].set(
+                        rrow_v, mode="drop"
+                    )
+                    cver = new_cache.ver.at[0, sidx, way].set(
+                        jnp.where(u_hit, nv, 0), mode="drop"
+                    )
+                    new_cache = new_cache._replace(values=cvals, ver=cver)
+                if has_insert:
+                    # drop the chip's own (now key-shifted) cached row
+                    i_hit = chit & (opc == OP_INSERT)
+                    sidx = jnp.where(i_hit, set_idx, cfg.cache_sets)
+                    ctags = new_cache.tags.at[0, sidx, way].set(
+                        -1, mode="drop"
+                    )
+                    new_cache = new_cache._replace(tags=ctags)
 
         # --- 7. per-lane results + statuses --------------------------------
-        out_found = jnp.zeros(q.shape, bool)
-        out_val = jnp.zeros(q.shape, jnp.int64)
-        if has_lookup:
-            is_lk = live & (opc == OP_LOOKUP)
-            # a lane resolved two-sided (offloaded, stale-forced, or peeked)
-            # takes the owning column's answer; the rest keep the local
-            # cached-descent result
-            two_sided = (offl_eff | sent_peek) if may_peek else offl_eff
-            out_found = jnp.where(
-                two_sided,
-                (rstat == STATUS_OK) & send & ~dropped_w,
-                found_leaf & ~shed,
-            ) & is_lk
-            out_val = jnp.where(
-                out_found, jnp.where(two_sided, rval, vals_leaf), 0
-            )
-        status = jnp.full(q.shape, STATUS_MISS, jnp.int32)
-        if has_writes:
-            is_w = live & ((opc == OP_UPDATE) | (opc == OP_INSERT))
-            shed_w = is_w & (shed | dropped_w)
-            status = jnp.where(
-                is_w & send & ~dropped_w & ~shed,
-                rstat,
-                jnp.where(shed_w, STATUS_SHED, STATUS_MISS),
-            )
-        lane_shed = shed | (send & dropped_w)
+        with jax.named_scope("dex/lanes"):
+            out_found = jnp.zeros(q.shape, bool)
+            out_val = jnp.zeros(q.shape, jnp.int64)
+            if has_lookup:
+                is_lk = live & (opc == OP_LOOKUP)
+                # a lane resolved two-sided (offloaded, stale-forced, or
+                # peeked) takes the owning column's answer; the rest keep the
+                # local cached-descent result
+                two_sided = (offl_eff | sent_peek) if may_peek else offl_eff
+                out_found = jnp.where(
+                    two_sided,
+                    (rstat == STATUS_OK) & send & ~dropped_w,
+                    found_leaf & ~shed,
+                ) & is_lk
+                out_val = jnp.where(
+                    out_found, jnp.where(two_sided, rval, vals_leaf), 0
+                )
+            status = jnp.full(q.shape, STATUS_MISS, jnp.int32)
+            if has_writes:
+                is_w = live & ((opc == OP_UPDATE) | (opc == OP_INSERT))
+                shed_w = is_w & (shed | dropped_w)
+                status = jnp.where(
+                    is_w & send & ~dropped_w & ~shed,
+                    rstat,
+                    jnp.where(shed_w, STATUS_SHED, STATUS_MISS),
+                )
+            lane_shed = shed | (send & dropped_w)
 
         # --- 7b. per-lane back-half pricing + latency histogram ------------
         # (obs/latency.py).  Two-sided trips price the simulator's offload
@@ -1156,15 +1261,15 @@ def make_dex_engine(
         # rule).  Each live routed lane then bins into exactly one
         # (op class, outcome path, bucket) cell — a pure per-device
         # scatter, so the plane adds zero collectives.
-        delivered_l = send & ~dropped_w
-        is_off = offl_eff & send
-        with jax.named_scope("dex/lat/offload"), routing.trace_phase("dex/lat"):
+        with _lat_scope("offload"):
+            delivered_l = send & ~dropped_w
+            is_off = offl_eff & send
             off_norm = delivered_l & is_off & ~stalled
             cost = cost + off_norm.astype(jnp.float32) * (
                 obs_latency.T_RPC + float(levels) * obs_latency.T_MEM
             )
         if may_peek:
-            with jax.named_scope("dex/lat/peer_peek"), routing.trace_phase("dex/lat"):
+            with _lat_scope("peer_peek"):
                 pk = delivered_l & sent_peek
                 cost = cost + pk.astype(jnp.float32) * (
                     obs_latency.T_RPC + jnp.where(
@@ -1172,15 +1277,12 @@ def make_dex_engine(
                     )
                 )
         if has_writes and not check_stale:
-            with (
-                jax.named_scope("dex/lat/write_through"),
-                routing.trace_phase("dex/lat"),
-            ):
+            with _lat_scope("write_through"):
                 wl = delivered_l & ~is_off & (
                     (opc == OP_UPDATE) | (opc == OP_INSERT)
                 )
                 cost = cost + wl.astype(jnp.float32) * obs_latency.T_WRITE
-        with jax.named_scope("dex/lat/bin"), routing.trace_phase("dex/lat"):
+        with _lat_scope("bin"):
             path = jnp.zeros(q.shape, jnp.int32)             # cache_hit
             path = jnp.where(fmiss, 1, path)                 # remote_fetch
             if may_peek:
@@ -1198,57 +1300,70 @@ def make_dex_engine(
             ).at[cls, path, bkt].add(live.astype(jnp.int64))
 
         # --- 8. back-half stats --------------------------------------------
-        n_shed = jnp.sum(lane_shed & live).astype(jnp.int64)
-        b_upd = jnp.zeros((1, N_STATS), jnp.int64)
-        b_upd = b_upd.at[0, STAT_OFFLOADS].set(n_off_msgs)
-        b_upd = b_upd.at[0, STAT_WRITES].set(n_write_msgs)
-        b_upd = b_upd.at[0, STAT_DROPS].set(n_shed)
-        b_upd = b_upd.at[0, STAT_SPLITS].set(
-            jnp.sum(status == STATUS_SPLIT).astype(jnp.int64)
-        )
-        b_upd = b_upd.at[0, STAT_PIPE_STALLS].set(n_stalls)
-        b_upd = b_upd.at[0, STAT_PEER_HITS].set(n_peer_hits)
-        b_upd = b_upd.at[0, STAT_PEER_MISSES].set(n_peer_misses)
+        with jax.named_scope("dex/lanes"):
+            n_shed = jnp.sum(lane_shed & live).astype(jnp.int64)
+            b_upd = jnp.zeros((1, N_STATS), jnp.int64)
+            b_upd = b_upd.at[0, STAT_OFFLOADS].set(n_off_msgs)
+            b_upd = b_upd.at[0, STAT_WRITES].set(n_write_msgs)
+            b_upd = b_upd.at[0, STAT_DROPS].set(n_shed)
+            b_upd = b_upd.at[0, STAT_SPLITS].set(
+                jnp.sum(status == STATUS_SPLIT).astype(jnp.int64)
+            )
+            b_upd = b_upd.at[0, STAT_PIPE_STALLS].set(n_stalls)
+            b_upd = b_upd.at[0, STAT_PEER_HITS].set(n_peer_hits)
+            b_upd = b_upd.at[0, STAT_PEER_MISSES].set(n_peer_misses)
 
         # --- 9. results back to the requesting lanes ------------------------
-        fields = [
-            out_found.astype(jnp.int64)[:, None],
-            out_val[:, None],
-            status.astype(jnp.int64)[:, None],
-            lane_shed.astype(jnp.int64)[:, None],
-        ]
-        if has_scan:
-            fields += [taken.astype(jnp.int64)[:, None], sc_k, sc_v]
-        resp_b = jnp.concatenate(fields, axis=-1)
-        width = resp_b.shape[-1]
-        resp_b = resp_b.reshape(n_route, cap, width)
+        with jax.named_scope("dex/lanes"):
+            fields = [
+                out_found.astype(jnp.int64)[:, None],
+                out_val[:, None],
+                status.astype(jnp.int64)[:, None],
+                lane_shed.astype(jnp.int64)[:, None],
+            ]
+            if has_scan:
+                fields += [taken.astype(jnp.int64)[:, None], sc_k, sc_v]
+            resp_b = jnp.concatenate(fields, axis=-1)
+            width = resp_b.shape[-1]
+            resp_b = resp_b.reshape(n_route, cap, width)
         with jax.named_scope("dex/route_back"):
             back_b = routing.route_exchange(resp_b, cfg, mesh, reverse=True)
-        out = routing.unpack_to_lanes(back_b, lane, b, 0)
-        res_found = (out[..., 0] != 0) & ~dropped_r
-        res_val = jnp.where(dropped_r, 0, out[..., 1])
-        res_status = jnp.where(
-            dropped_r, STATUS_SHED, out[..., 2].astype(jnp.int32)
-        )
-        if not has_writes:
+        with jax.named_scope("dex/lanes"):
+            out = routing.unpack_to_lanes(back_b, lane, b, 0)
+            res_found = (out[..., 0] != 0) & ~dropped_r
+            res_val = jnp.where(dropped_r, 0, out[..., 1])
             res_status = jnp.where(
-                dropped_r & (q.shape[0] > 0), STATUS_SHED, STATUS_MISS
-            ).astype(jnp.int32)
-        res_shed = (out[..., 3] != 0) | dropped_r
-        lane_out = [res_found, res_val, res_status, res_shed]
-        if has_scan:
-            res_taken = jnp.where(
-                dropped_r, -1, out[..., 4]
-            ).astype(jnp.int32)
-            res_k = jnp.where(
-                dropped_r[:, None], KEY_MAX, out[..., 5 : 5 + mc]
+                dropped_r, STATUS_SHED, out[..., 2].astype(jnp.int32)
             )
-            res_v = jnp.where(
-                dropped_r[:, None], 0, out[..., 5 + mc : 5 + 2 * mc]
-            )
-            lane_out += [res_k, res_v, res_taken]
+            if not has_writes:
+                res_status = jnp.where(
+                    dropped_r & (q.shape[0] > 0), STATUS_SHED, STATUS_MISS
+                ).astype(jnp.int32)
+            res_shed = (out[..., 3] != 0) | dropped_r
+            lane_out = [res_found, res_val, res_status, res_shed]
+            if has_scan:
+                res_taken = jnp.where(
+                    dropped_r, -1, out[..., 4]
+                ).astype(jnp.int32)
+                res_k = jnp.where(
+                    dropped_r[:, None], KEY_MAX, out[..., 5 : 5 + mc]
+                )
+                res_v = jnp.where(
+                    dropped_r[:, None], 0, out[..., 5 + mc : 5 + 2 * mc]
+                )
+                lane_out += [res_k, res_v, res_taken]
         return (new_pk, new_pv, new_occ, new_versions, new_cache, b_upd,
                 h_upd, lane_out)
+
+    def _accumulate(stats, f_upd, b_upd, lat_hist, h_upd, lat_audit, a_upd):
+        """The step's counter, histogram and audit increments."""
+        with jax.named_scope("dex/lanes"):
+            new_stats = stats + f_upd + b_upd
+        with _lat_scope("bin"):
+            new_hist = lat_hist + h_upd[None]
+        with _lat_scope("audit"):
+            new_audit = lat_audit + a_upd[None]
+        return new_stats, new_hist, new_audit
 
     def local_fn(pool, occupancy, cache, boundaries, miss_ema, stats, demand,
                  versions, succ, lat_hist, lat_audit, rtk, rth, rts, rtl,
@@ -1262,9 +1377,9 @@ def make_dex_engine(
          lane_out) = _run_back(
             pool, occupancy, new_cache, versions, carry, b, check_stale=False,
         )
-        new_stats = stats + f_upd + b_upd
-        new_hist = lat_hist + h_upd[None]
-        new_audit = lat_audit + a_upd[None]
+        new_stats, new_hist, new_audit = _accumulate(
+            stats, f_upd, b_upd, lat_hist, h_upd, lat_audit, a_upd
+        )
         outs = [new_cache, new_ema, new_stats, new_demand, new_hist,
                 new_audit] + lane_out
         if has_writes:
@@ -1293,11 +1408,11 @@ def make_dex_engine(
                 pool, occupancy, cache_f, versions, carried, b,
                 check_stale=True,
             )
-        new_stats = stats + f_upd + b_upd
         # the histogram lags STAT_OPS by one batch here (a lane bins when
         # its back half lands); the drain step closes the gap exactly
-        new_hist = lat_hist + h_upd[None]
-        new_audit = lat_audit + a_upd[None]
+        new_stats, new_hist, new_audit = _accumulate(
+            stats, f_upd, b_upd, lat_hist, h_upd, lat_audit, a_upd
+        )
         outs = [new_cache, new_ema, new_stats, new_demand, new_hist,
                 new_audit]
         outs += [carry_out[k] for k in carry_keys]
@@ -1328,12 +1443,34 @@ def make_dex_engine(
         if do_descent else 0,
         "scan_hops": hops,
         "pipeline": bool(pipeline),
-        # jax.named_scope labels annotating the jitted program for profiler
-        # traces (repro/obs/trace.py profiler_annotations); metadata only —
-        # they add no ops and no collectives
+        # the jax.named_scope families that label every op of the step in a
+        # profiler trace (the first ``dex/`` component of an op_name; no
+        # family nests in another); metadata only — they add no ops and no
+        # collectives
         "phases": ("dex/route", "dex/descent", "dex/scan", "dex/fused_a2a",
-                   "dex/apply", "dex/lat", "dex/route_back"),
+                   "dex/apply", "dex/lat", "dex/route_back", "dex/locate",
+                   "dex/offload", "dex/coherence", "dex/lanes"),
     }
+
+    enabled_codes = [
+        code for flag, code in [
+            (has_lookup, OP_LOOKUP), (has_update, OP_UPDATE),
+            (has_insert, OP_INSERT), (has_scan, OP_SCAN),
+        ] if flag
+    ]
+
+    def mask_opcodes(opcodes, keys, values):
+        # opcodes outside the static ``ops`` set are true no-ops: their
+        # keys are masked before routing, so they consume no bucket
+        # capacity, mint no demand/stats and return inactive results
+        with jax.named_scope("dex/lanes"):
+            opcodes = opcodes.astype(jnp.int32)
+            keys = keys.astype(jnp.int64)
+            allowed = jnp.zeros(opcodes.shape, bool)
+            for code in enabled_codes:
+                allowed = allowed | (opcodes == code)
+            keys = jnp.where(allowed, keys, KEY_MAX)
+            return opcodes, keys, values.astype(jnp.int64)
 
     if not pipeline:
         sharded = routing.shard_map_compat(
@@ -1351,32 +1488,17 @@ def make_dex_engine(
             ),
         )
 
-        enabled_codes = [
-            code for flag, code in [
-                (has_lookup, OP_LOOKUP), (has_update, OP_UPDATE),
-                (has_insert, OP_INSERT), (has_scan, OP_SCAN),
-            ] if flag
-        ]
-
         def engine(state: DexState, opcodes: jax.Array, keys: jax.Array,
                    values: jax.Array):
             if keys.shape[0] == 0:
                 return state, _empty_result(0, mc, has_scan)
-            opcodes = opcodes.astype(jnp.int32)
-            keys = keys.astype(jnp.int64)
-            # opcodes outside the static ``ops`` set are true no-ops: their
-            # keys are masked before routing, so they consume no bucket
-            # capacity, mint no demand/stats and return inactive results
-            allowed = jnp.zeros(opcodes.shape, bool)
-            for code in enabled_codes:
-                allowed = allowed | (opcodes == code)
-            keys = jnp.where(allowed, keys, KEY_MAX)
+            opcodes, keys, values = mask_opcodes(opcodes, keys, values)
             res = sharded(
                 state.pool, state.occupancy, state.cache, state.boundaries,
                 state.miss_ema, state.stats, state.route_demand,
                 state.versions, state.succ, state.lat_hist, state.lat_audit,
                 state.rt_keys, state.rt_hi, state.rt_sub, state.rt_local,
-                state.rt_ver, opcodes, keys, values.astype(jnp.int64),
+                state.rt_ver, opcodes, keys, values,
             )
             res = list(res)
             new_state = state
@@ -1429,12 +1551,6 @@ def make_dex_engine(
         ),
     )
 
-    enabled_codes = [
-        code for flag, code in [
-            (has_lookup, OP_LOOKUP), (has_update, OP_UPDATE),
-            (has_insert, OP_INSERT), (has_scan, OP_SCAN),
-        ] if flag
-    ]
     lane_sharding = NamedSharding(mesh, lanes)
 
     def init_carry(b_global: int):
@@ -1484,18 +1600,13 @@ def make_dex_engine(
         )
 
     def pipe_step(state: DexState, carry, opcodes, keys, values):
-        opcodes = opcodes.astype(jnp.int32)
-        keys = keys.astype(jnp.int64)
-        allowed = jnp.zeros(opcodes.shape, bool)
-        for code in enabled_codes:
-            allowed = allowed | (opcodes == code)
-        keys = jnp.where(allowed, keys, KEY_MAX)
+        opcodes, keys, values = mask_opcodes(opcodes, keys, values)
         res = sharded_pipe(
             state.pool, state.occupancy, state.cache, state.boundaries,
             state.miss_ema, state.stats, state.route_demand, state.versions,
             state.succ, state.lat_hist, state.lat_audit, state.rt_keys,
             state.rt_hi, state.rt_sub, state.rt_local, state.rt_ver,
-            tuple(carry), opcodes, keys, values.astype(jnp.int64),
+            tuple(carry), opcodes, keys, values,
         )
         res = list(res)
         new_state = state

@@ -611,8 +611,10 @@ def run_smo(
     while pending.any() and rounds < max_rounds:
         before = splits_done(state)
         # obs is an optional telemetry batch (repro/obs/timeline.py); each
-        # SMO round is a separate fenced host phase in the trace
-        with obs_phase(obs, f"smo/round{rounds}"):
+        # SMO round is a separate fenced host phase in the timeline, and a
+        # ``dex/smo/round`` span in a profiler trace
+        with obs_phase(obs, f"smo/round{rounds}", span="dex/smo/round",
+                       round=rounds):
             state, st_r = smo(
                 state,
                 jnp.asarray(np.where(pending, keys, KEY_MAX)),

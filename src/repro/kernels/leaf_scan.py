@@ -146,6 +146,7 @@ def leaf_scan(
             jax.ShapeDtypeStruct((bp, 1), jnp.int32),
         ],
         interpret=use_interpret() if interpret is None else interpret,
+        name="leaf_scan",
     )(khi, klo, vhi, vlo, shi[:, None], slo[:, None], counts[:, None])
     out_k = _join_i64(okhi, oklo)
     out_v = _join_i64(ovhi, ovlo)

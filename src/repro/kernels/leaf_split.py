@@ -217,6 +217,7 @@ def leaf_split(
         out_shape=[jax.ShapeDtypeStruct((qp, f), jnp.int32)] * 8
         + [jax.ShapeDtypeStruct((qp, 1), jnp.int32)] * 5,
         interpret=use_interpret() if interpret is None else interpret,
+        name="leaf_split",
     )(khi, klo, vhi, vlo, ikh, ikl, ivh, ivl)
     lkh, lkl, lvh, lvl, rkh, rkl, rvh, rvl, occl, occr, sh, sl, did = outs
     return (

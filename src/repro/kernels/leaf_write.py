@@ -231,6 +231,7 @@ def leaf_write(
             jax.ShapeDtypeStruct((qp, 1), jnp.int32),
         ],
         interpret=use_interpret() if interpret is None else interpret,
+        name="leaf_write",
     )(khi, klo, vhi, vlo, upd_slot.astype(jnp.int32), uvh, uvl,
       ikh, ikl, ivh, ivl)
     out_k = _join_i64(okh, okl)

@@ -12,8 +12,7 @@ simulator (Plane A) and the benchmark driver.
     with ``block_until_ready`` fencing and counter deltas piggybacked on
     the engine's existing psums (zero added collectives).
   * :mod:`repro.obs.trace` — Chrome trace-event JSON export of a timeline
-    (viewable in Perfetto / chrome://tracing) plus the optional
-    ``jax.profiler`` annotation hook.
+    (viewable in Perfetto / chrome://tracing).
   * :mod:`repro.obs.drift` — the mesh-vs-sim counter comparison
     (``assert_plane_agreement``) with per-metric tolerances and a readable
     drift report, replacing the ad-hoc checks the mesh benchmarks used to

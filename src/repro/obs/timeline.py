@@ -18,7 +18,9 @@ measure.  So the timeline works at two resolutions:
 
 Inside the jitted program, ``jax.named_scope`` annotations (added in
 ``core/engine.py``) label the phases for ``jax.profiler`` traces; they are
-metadata only and cost nothing at run time.
+metadata only and cost nothing at run time.  The host phases open a
+``jax.profiler.TraceAnnotation`` whether or not a timeline records them
+(:func:`obs_phase`), so they land in a profiler trace too.
 
 Shed-lane retry latency is tracked per op class as *batches to completion*:
 ``record_retry("insert", rounds)`` after a retry loop.
@@ -351,9 +353,22 @@ class BatchTimeline:
         }
 
 
-def obs_phase(obs: Optional[Any], name: str):
-    """Phase hook used by core/smo.py and core/repartition.py: ``obs`` is a
-    :class:`_Batch` (or anything with ``.phase``), or None for a no-op."""
-    if obs is None:
-        return contextlib.nullcontext()
-    return obs.phase(name)
+@contextlib.contextmanager
+def obs_phase(obs: Optional[Any], name: str, *, span: Optional[str] = None,
+              **args: Any):
+    """Phase hook used by core/smo.py and core/repartition.py.
+
+    Always opens a ``jax.profiler.TraceAnnotation`` named ``span`` (default
+    ``"dex/" + name``) with ``args`` as its arguments, so the phase lands in
+    a profiler trace on the same clock as the device's ops; with the
+    profiler off it costs a few microseconds a call.  ``obs`` is a :class:`_Batch` (or anything with
+    ``.phase``) that also records the phase as ``name``, or None; the phase
+    object (or None) is what the block receives."""
+    from jax.profiler import TraceAnnotation
+
+    with TraceAnnotation(span or "dex/" + name, **args):
+        if obs is None:
+            yield None
+        else:
+            with obs.phase(name) as ph:
+                yield ph

@@ -16,16 +16,10 @@ Emits the JSON-object flavour of the Trace Event Format (``{"traceEvents":
 * "M" metadata events naming every process/thread.
 
 Timestamps are microseconds from the timeline epoch, as the format requires.
-
-Also provides :func:`profiler_annotations`, the optional ``jax.profiler``
-hook: a context manager that opens a ``TraceAnnotation`` so the engine's
-``jax.named_scope`` phase labels land in a profiler trace alongside the
-host-side batches.
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
 from typing import Any, Dict, List, Optional
 
@@ -172,24 +166,3 @@ def write_trace(timeline: BatchTimeline, path: str) -> str:
     with open(path, "w") as f:
         json.dump(to_trace_events(timeline), f)
     return path
-
-
-@contextlib.contextmanager
-def profiler_annotations(label: str, enabled: bool = True):
-    """Optional ``jax.profiler`` hook: annotate the enclosed dispatches so
-    the engine's ``jax.named_scope`` phase labels show up under ``label`` in
-    a profiler trace.  No-op (and jax-import-free) when disabled or when the
-    profiler API is unavailable.
-    """
-    if not enabled:
-        yield
-        return
-    try:
-        import jax.profiler as _prof
-
-        ctx = _prof.TraceAnnotation(label)
-    except Exception:  # pragma: no cover - profiler backend missing
-        yield
-        return
-    with ctx:
-        yield

@@ -211,6 +211,53 @@ def test_timed_call_and_obs_phase_nullcontext():
     assert tl.batches[0].phase_seconds().keys() == {"smo/drain"}
 
 
+def test_smo_rounds_land_in_a_profiler_trace(tmp_path):
+    """Each SMO round opens a ``dex/smo/round`` profiler span whose argument
+    is the round's number, and is still a timeline phase of its own."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.core import smo
+    from repro.core.nodes import KEY_MAX
+    from repro.core.write import STATUS_OK, STATUS_SPLIT
+
+    class _State:
+        def __init__(self, stats):
+            self.stats = stats
+
+    def fake_smo(state, keys, values):
+        # the first round only splits; the second settles every lane
+        splits_so_far = state.stats[0, dex_mod.STAT_SMO_SPLITS]
+        stats = state.stats.copy()
+        stats[0, dex_mod.STAT_SMO_SPLITS] += 1
+        live = np.asarray(keys) != KEY_MAX
+        st = STATUS_SPLIT if splits_so_far == 0 else STATUS_OK
+        return _State(stats), np.where(live, st, 0).astype(np.int32)
+
+    keys = np.array([5, KEY_MAX, 9], np.int64)
+    tl = BatchTimeline("smo")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tl.batch("b") as ob:
+            _, status, rounds = smo.run_smo(
+                fake_smo, _State(np.zeros((1, dex_mod.N_STATS), np.int64)),
+                keys, keys, obs=ob,
+            )
+    finally:
+        jax.profiler.stop_trace()
+    assert rounds == 2 and list(status[[0, 2]]) == [STATUS_OK, STATUS_OK]
+    assert tl.batches[0].phase_seconds().keys() == {"smo/round0",
+                                                    "smo/round1"}
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = [dict(e.stats)["round"]
+             for p in ProfileData.from_file(path).planes
+             for ln in p.lines for e in ln.events
+             if e.name == "dex/smo/round"]
+    assert sorted(spans) == [0, 1]
+
+
 # ---------------------------------------------------------------------------
 # Trace export
 # ---------------------------------------------------------------------------
@@ -248,8 +295,13 @@ def test_trace_events_schema(tmp_path):
 
 
 def test_profiler_annotations_is_reentrant_noop_when_disabled():
-    with trace.profiler_annotations("x", enabled=False):
-        pass
+    # the host phases' profiler annotations (obs_phase) nest, and with the
+    # profiler off they record nothing and hand the block no phase
+    with obs_phase(None, "smo/drain") as outer:
+        with obs_phase(None, "smo/round0", span="dex/smo/round",
+                       round=0) as inner:
+            pass
+    assert outer is None and inner is None
 
 
 # ---------------------------------------------------------------------------
