@@ -216,7 +216,7 @@ def _drive(n_chips, n_keys, seed, n_batches, log, compiles) -> dict:
     pool_leaves = jax.tree.leaves(state.pool)
     pool_bytes = sum(s.data.nbytes for a in pool_leaves
                      for s in a.addressable_shards)
-    pool_devices = {s.device for s in state.pool.pool_keys.addressable_shards}
+    pool_devices = {s.device for s in state.pool.pool_keys.hi.addressable_shards}
     if len(pool_devices) != n_chips:
         raise SmokeError(f"pool shards on {len(pool_devices)} devices, "
                          f"expected {n_chips}")

@@ -51,7 +51,15 @@ from repro.core.fleet_cache import (  # noqa: F401  (re-exported compat names)
     init_cache,
 )
 from repro.core.nodes import FANOUT, KEY_MAX
-from repro.core.pool import PoolMeta, SubtreePool, blocks, initial_succ
+from repro.core.pool import (
+    PoolMeta,
+    SubtreePool,
+    blocks,
+    initial_succ,
+    Words,
+    pool_specs,
+    split_words,
+)
 
 NODE_ROW_BYTES = FANOUT * 8 * 3  # keys + children + values on the wire
 OFFLOAD_REQ_BYTES = 16
@@ -186,9 +194,7 @@ def init_state(
         miss_ema=jnp.ones((cfg.n_devices, cfg.n_memory, levels), jnp.float32),
         stats=jnp.zeros((cfg.n_devices, N_STATS), jnp.int64),
         versions=jnp.zeros((cfg.n_devices, n_nodes), jnp.int32),
-        occupancy=jnp.sum(
-            blocks(pool.pool_keys != KEY_MAX), axis=-1, dtype=jnp.int32
-        ),
+        occupancy=_occupancy(pool.pool_keys),
         route_demand=jnp.zeros((cfg.n_devices, cfg.n_route), jnp.int64),
         succ=jnp.broadcast_to(succ0[None, :], (cfg.n_devices, n_nodes)),
         n_alloc=jnp.full((meta.n_subtrees_padded,), base, jnp.int32),
@@ -206,6 +212,15 @@ def init_state(
         rt_local=jnp.zeros((max(cfg.route_table_slots, 1),), jnp.int32),
         rt_ver=jnp.full((max(cfg.route_table_slots, 1),), -1, jnp.int32),
     )
+
+
+@jax.jit
+def _occupancy(pool_keys: Words) -> jax.Array:
+    """Keys per node ``[S, C]`` of a key word plane: slots whose words are
+    not ``KEY_MAX``'s (one fused pass, no int64 copy of the plane)."""
+    kmax = split_words(KEY_MAX)
+    real = (blocks(pool_keys.hi) != kmax.hi) | (blocks(pool_keys.lo) != kmax.lo)
+    return jnp.sum(real, axis=-1, dtype=jnp.int32)
 
 
 def make_dex_mesh(devices: "Sequence[jax.Device] | None" = None):
@@ -229,13 +244,8 @@ def state_shardings(mesh, cfg: DexMeshConfig):
     def ns(spec):
         return jax.sharding.NamedSharding(mesh, spec)
 
-    pool_spec = SubtreePool(
-        top_keys=ns(P()),
-        top_children=ns(P()),
-        pool_keys=ns(P(cfg.memory_axis)),
-        pool_children=ns(P(cfg.memory_axis)),
-        pool_values=ns(P(cfg.memory_axis)),
-    )
+    pool_spec = jax.tree.map(ns, pool_specs(cfg.memory_axis),
+                             is_leaf=lambda s: isinstance(s, P))
     cache_spec = DexCache(
         tags=ns(dev), keys=ns(dev), children=ns(dev), values=ns(dev),
         fifo=ns(dev), ver=ns(dev),

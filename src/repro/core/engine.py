@@ -140,7 +140,7 @@ from repro.core.dex import (
 )
 from repro.core.fleet_cache import DexCache, cached_fetch_level
 from repro.core.nodes import FANOUT, KEY_MAX
-from repro.core.pool import PoolMeta, SubtreePool, node_rows, top_walk
+from repro.core.pool import PoolMeta, node_rows, pool_specs, top_walk
 from repro.core.write import (
     STATUS_MISS,
     STATUS_OK,
@@ -1422,18 +1422,13 @@ def make_dex_engine(
         return tuple(outs)
 
     dev_spec = P(cfg.all_axes)
-    pool_specs = SubtreePool(
-        top_keys=P(),
-        top_children=P(),
-        pool_keys=P(cfg.memory_axis),
-        pool_children=P(cfg.memory_axis),
-        pool_values=P(cfg.memory_axis),
-    )
+    pool_in = pool_specs(cfg.memory_axis)
     cache_specs = DexCache(
         tags=dev_spec, keys=dev_spec, children=dev_spec, values=dev_spec,
         fifo=dev_spec, ver=dev_spec,
     )
     mem = P(cfg.memory_axis)
+    words = pool_in.pool_keys
     lanes = P(cfg.all_axes)
 
     plan = {
@@ -1476,12 +1471,12 @@ def make_dex_engine(
         sharded = routing.shard_map_compat(
             local_fn,
             mesh=mesh,
-            in_specs=(pool_specs, mem, cache_specs, P(), dev_spec, dev_spec,
+            in_specs=(pool_in, mem, cache_specs, P(), dev_spec, dev_spec,
                       dev_spec, dev_spec, dev_spec, dev_spec, dev_spec,
                       P(), P(), P(), P(), P(),
                       lanes, lanes, lanes),
             out_specs=tuple(
-                ([mem, mem, mem, dev_spec] if has_writes else [])
+                ([words, words, mem, dev_spec] if has_writes else [])
                 + [cache_specs, dev_spec, dev_spec, dev_spec, dev_spec,
                    dev_spec, lanes, lanes, lanes, lanes]
                 + ([lanes, lanes, lanes] if has_scan else [])
@@ -1538,12 +1533,12 @@ def make_dex_engine(
     sharded_pipe = routing.shard_map_compat(
         local_pipe,
         mesh=mesh,
-        in_specs=(pool_specs, mem, cache_specs, P(), dev_spec, dev_spec,
+        in_specs=(pool_in, mem, cache_specs, P(), dev_spec, dev_spec,
                   dev_spec, dev_spec, dev_spec, dev_spec, dev_spec,
                   P(), P(), P(), P(), P(),
                   carry_specs, lanes, lanes, lanes),
         out_specs=tuple(
-            ([mem, mem, mem, dev_spec] if has_writes else [])
+            ([words, words, mem, dev_spec] if has_writes else [])
             + [cache_specs, dev_spec, dev_spec, dev_spec, dev_spec, dev_spec]
             + list(carry_specs)
             + [lanes, lanes, lanes, lanes]

@@ -4,9 +4,9 @@ The paper stores every subtree rooted at level M on a single memory server
 (§3 Index Placement) so offloaded traversals never chase pointers across
 servers.  On a TPU mesh the equivalent is a *blocked* layout:
 
-    pool_keys    : [n_subtrees, subtree_cap * FANOUT]   -- axis 0 sharded
-    pool_children: [n_subtrees, subtree_cap * FANOUT]      over the `model`
-    pool_values  : [n_subtrees, subtree_cap * FANOUT]      axis
+    pool_keys    : Words of [n_subtrees, subtree_cap * FANOUT]  -- axis 0
+    pool_children:          [n_subtrees, subtree_cap * FANOUT]     sharded
+    pool_values  : Words of [n_subtrees, subtree_cap * FANOUT]     over `model`
 
 with all levels above M ("top tree") replicated on every chip — these are
 the paper's root-side nodes that are effectively always cached.  Local node
@@ -23,6 +23,22 @@ XLA lays such a pool out subtree-minor and re-lays the whole of it out for
 every row gather and scatter (about three pool-sized temporaries per
 engine step by the TPU compiler's memory analysis).  Block rows are
 a multiple of 128 lanes, and a row gather reads 64 lanes in place.
+
+**Word planes.**  The int64 key and value planes are each stored as two
+int32 planes of the same ``[S, C * FANOUT]`` shape, a :class:`Words` pair:
+``hi`` is ``x >> 32``, ``lo`` the low 32 bits reinterpreted as int32
+(:func:`split_words` / :func:`join_words`; ``KEY_MAX`` is ``(0x7FFFFFFF,
+-1)``).  A TPU has no 64-bit lanes: an int64 plane in the engine's state
+made the compiler split the whole of it into two 32-bit halves and combine
+them back on every step, three pool-sized passes to serve a few thousand
+rows.  Each word is a plane of its own, laid out as ``pool_children`` is:
+a stacked ``[2, S, C * FANOUT]`` array gets another default layout on the
+TPU than the step's row loops use, and the step then copied the whole of it
+into theirs and back.  :func:`node_rows` gathers both words of a row with
+the one index and joins them to int64 after the gather;
+:func:`set_node_rows` splits rows before its scatter.  Nothing joins or
+splits a whole plane inside a compiled step; host code reads the int64 view
+through :func:`keys_i64` / :func:`values_i64`.
 
 **Free-list headroom (the on-mesh SMO allocation layer).**  Each block is
 built ``headroom`` fraction larger than the bulk layout needs; the extra
@@ -45,8 +61,17 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core.nodes import FANOUT, KEY_MAX, KEY_MIN, NULL
+
+
+class Words(NamedTuple):
+    """An int64 array stored as its two int32 words (see the module
+    docstring): ``hi = x >> 32`` and ``lo`` = the low 32 bits as int32."""
+
+    hi: jax.Array
+    lo: jax.Array
 
 
 class SubtreePool(NamedTuple):
@@ -56,10 +81,23 @@ class SubtreePool(NamedTuple):
     top_keys: jax.Array       # [T, FANOUT] int64
     top_children: jax.Array   # [T, FANOUT] int32; at level M+1 the entries
                               # are *subtree ids* (pool axis-0 indices)
-    # subtree blocks (levels M..0) as block rows (see module docstring)
-    pool_keys: jax.Array      # [S, C * FANOUT] int64
+    # subtree blocks (levels M..0) as block rows; the int64 keys and values
+    # as int32 word planes (see module docstring)
+    pool_keys: Words          # 2 x [S, C * FANOUT] int32 words of int64 keys
     pool_children: jax.Array  # [S, C * FANOUT] int32 (subtree-local ids)
-    pool_values: jax.Array    # [S, C * FANOUT] int64 (leaf payloads)
+    pool_values: Words        # 2 x [S, C * FANOUT] int32 words of payloads
+
+
+def pool_specs(axis: str) -> SubtreePool:
+    """PartitionSpecs of the pool's planes: the top tree replicated, each
+    block plane sharded on its subtree axis over ``axis``."""
+    return SubtreePool(
+        top_keys=P(),
+        top_children=P(),
+        pool_keys=Words(P(axis), P(axis)),
+        pool_children=P(axis),
+        pool_values=Words(P(axis), P(axis)),
+    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -187,9 +225,12 @@ def build_pool_host(
     cap = base_cap + int(np.ceil(base_cap * headroom))
     leaf_start = int(offs[-2])
 
-    PK = np.full((S, cap, FANOUT), KEY_MAX, dtype=np.int64)
+    # the key and value planes are built as word planes in place: no int64
+    # copy of the whole pool is ever held
+    PK = np.empty((2, S, cap, FANOUT), dtype=np.int32)
+    PK[0], PK[1] = split_words(KEY_MAX, np)
     PC = np.full((S, cap, FANOUT), NULL, dtype=np.int32)
-    PV = np.zeros((S, cap, FANOUT), dtype=np.int64)
+    PV = np.zeros((2, S, cap, FANOUT), dtype=np.int32)
 
     # pad keys to full leaves for reshaping
     pad = (-n) % per_node
@@ -198,15 +239,25 @@ def build_pool_host(
     leaf_k = kp.reshape(n_leaves, per_node)
     leaf_v = vp.reshape(n_leaves, per_node)
 
+    # place the leaves of every full block at once, then the last block's
+    n_full = n_leaves // leaves_per_subtree
+    full = n_full * leaves_per_subtree
+    leaves = np.s_[:n_full, leaf_start : leaf_start + leaves_per_subtree,
+                   :per_node]
+    shape = (n_full, leaves_per_subtree, per_node)
+    _put_words(PK, leaves, leaf_k[:full].reshape(shape))
+    _put_words(PV, leaves, leaf_v[:full].reshape(shape))
+    if full < n_leaves:
+        tail = np.s_[n_full, leaf_start : leaf_start + n_leaves - full,
+                     :per_node]
+        _put_words(PK, tail, leaf_k[full:])
+        _put_words(PV, tail, leaf_v[full:])
+
     subtree_mins = np.full((S,), KEY_MAX, dtype=np.int64)
 
     for s in range(n_subtrees):
         lk = leaf_k[s * leaves_per_subtree : (s + 1) * leaves_per_subtree]
-        lv = leaf_v[s * leaves_per_subtree : (s + 1) * leaves_per_subtree]
         nl = lk.shape[0]
-        # place leaves
-        PK[s, leaf_start : leaf_start + nl, :per_node] = lk
-        PV[s, leaf_start : leaf_start + nl, :per_node] = lv
         # routing minima for this subtree's leaves
         mins = lk[:, 0].copy()
         child_ids = np.arange(leaf_start, leaf_start + nl, dtype=np.int32)
@@ -219,7 +270,7 @@ def build_pool_host(
                 cm = mins[i * per_node : (i + 1) * per_node]
                 ch = child_ids[i * per_node : (i + 1) * per_node]
                 nid = lvl_off + i
-                PK[s, nid, : cm.size] = cm
+                _put_words(PK, np.s_[s, nid, : cm.size], cm)
                 PC[s, nid, : ch.size] = ch
                 new_mins[i] = cm[0]
             mins = new_mins
@@ -262,9 +313,9 @@ def build_pool_host(
     pool = SubtreePool(
         top_keys=TK,
         top_children=TC,
-        pool_keys=PK.reshape(S, cap * FANOUT),
+        pool_keys=Words(*PK.reshape(2, S, cap * FANOUT)),
         pool_children=PC.reshape(S, cap * FANOUT),
-        pool_values=PV.reshape(S, cap * FANOUT),
+        pool_values=Words(*PV.reshape(2, S, cap * FANOUT)),
     )
     meta = PoolMeta(
         level_m=level_m,
@@ -368,7 +419,7 @@ def compress_separators(pool: SubtreePool, meta: PoolMeta) -> SepPlanes:
     """Build the compressed separator planes for every pool row at load
     (host-side; core/smo.py ``refresh_sep_planes`` keeps them correct
     across on-mesh splits without a full rebuild)."""
-    pk = blocks(np.asarray(pool.pool_keys))
+    pk = blocks(keys_i64(pool))
     s, c, f = pk.shape
     prefix, nbits, suffix = compress_rows(pk.reshape(s * c, f))
     return SepPlanes(
@@ -418,6 +469,43 @@ def sep_compression_stats(sep: SepPlanes, meta: PoolMeta) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def split_words(x, xp=jnp) -> Words:
+    """int64 ``x`` -> its int32 :class:`Words`: the high word ``x >> 32``
+    and the low 32 bits reinterpreted as int32 (``xp`` is ``np`` on the
+    host, ``jnp`` in a program)."""
+    x = xp.asarray(x).astype(xp.int64)
+    hi = (x >> 32).astype(xp.int32)
+    lo = (x & 0xFFFFFFFF).astype(xp.uint32).astype(xp.int32)
+    return Words(hi, lo)
+
+
+def join_words(words, xp=jnp):
+    """A ``(hi, lo)`` pair of int32 words -> the int64 values (inverse of
+    :func:`split_words`)."""
+    hi, lo = words
+    return (hi.astype(xp.int64) << 32) | lo.astype(xp.uint32).astype(xp.int64)
+
+
+def _put_words(plane, index, x) -> None:
+    """Host build: write int64 ``x`` into the numpy word planes ``plane``
+    (``[2, ...]``) at ``plane[:, *index]`` (numpy's int64 -> int32
+    assignment keeps the low 32 bits)."""
+    plane[0][index] = x >> 32
+    plane[1][index] = x
+
+
+def keys_i64(pool: SubtreePool, xp=np):
+    """The pool's keys as one int64 ``[S, C * FANOUT]`` plane (``np``: a
+    host copy; ``jnp``: an array on the planes' devices)."""
+    return join_words([xp.asarray(w) for w in pool.pool_keys], xp)
+
+
+def values_i64(pool: SubtreePool, xp=np):
+    """The pool's values as one int64 ``[S, C * FANOUT]`` plane, as
+    :func:`keys_i64`."""
+    return join_words([xp.asarray(w) for w in pool.pool_values], xp)
+
+
 def blocks(plane):
     """``[S, C * FANOUT]`` block rows -> the ``[S, C, FANOUT]`` node view
     (numpy or jax; on a device this is a relayout — host code and one-off
@@ -438,9 +526,13 @@ def _row_index(plane, subtree, local):
     return jnp.stack([st, lo * FANOUT], axis=-1)
 
 
-def node_rows(plane: jax.Array, subtree, local) -> jax.Array:
+def node_rows(plane, subtree, local) -> jax.Array:
     """Rows ``[..., FANOUT]`` of nodes ``(subtree, local)`` of a block-row
-    plane — ``blocks(plane)[subtree, local]`` without a relayout."""
+    plane — ``blocks(plane)[subtree, local]`` without a relayout.  Of a
+    :class:`Words` plane both words of a row are gathered with the one
+    index and the rows come back joined to int64."""
+    if isinstance(plane, Words):
+        return join_words([node_rows(w, subtree, local) for w in plane])
     idx = _row_index(plane, subtree, local)
     dn = jax.lax.GatherDimensionNumbers(
         offset_dims=(idx.ndim - 1,), collapsed_slice_dims=(0,),
@@ -450,10 +542,14 @@ def node_rows(plane: jax.Array, subtree, local) -> jax.Array:
                           mode="clip")
 
 
-def set_node_rows(plane: jax.Array, subtree, local, rows) -> jax.Array:
+def set_node_rows(plane, subtree, local, rows):
     """``plane`` with ``rows [..., FANOUT]`` written at nodes ``(subtree,
     local)``; nodes outside the plane are dropped, the same contract as
-    ``.at[subtree, local].set(rows, mode="drop")`` on the node view."""
+    ``.at[subtree, local].set(rows, mode="drop")`` on the node view.  Rows
+    bound for a :class:`Words` plane are split into their words first."""
+    if isinstance(plane, Words):
+        return Words(*(set_node_rows(w, subtree, local, r)
+                       for w, r in zip(plane, split_words(rows))))
     idx = _row_index(plane, subtree, local)
     dn = jax.lax.ScatterDimensionNumbers(
         update_window_dims=(idx.ndim - 1,), inserted_window_dims=(0,),

@@ -51,7 +51,7 @@ from repro.core import fleet_cache
 from repro.core.dex import N_STATS, STAT_DROPS, STAT_OPS, DexState
 from repro.core.nodes import KEY_MAX, KEY_MIN
 from repro.core.partition import LogicalPartitions
-from repro.core.pool import PoolMeta, blocks
+from repro.core.pool import PoolMeta, blocks, keys_i64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,7 +86,8 @@ def node_key_ranges(
     *,
     with_levels: bool = False,
 ):
-    """Per-node fence ranges ``(gids, lo, hi)`` for every real pool node.
+    """Per-node fence ranges ``(gids, lo, hi)`` for every real pool node,
+    from the int64 key plane ``pool_keys`` (:func:`repro.core.pool.keys_i64`).
 
     Each node's range runs from its first key to the next node's first key
     at the same level (the leftmost node of a level covers from ``KEY_MIN``
@@ -196,7 +197,7 @@ def install_boundaries(
     Returns ``(new_state, nodes_invalidated, shared_before, shared_after)``.
     """
     gids, lo, hi = node_key_ranges(
-        state.pool.pool_keys, meta, state.pool.pool_children
+        keys_i64(state.pool), meta, state.pool.pool_children
     )
     moved = moved_intervals(old, new)
     affected = np.zeros(gids.shape, dtype=bool)

@@ -41,7 +41,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.dex import DexState
 from repro.core.nodes import KEY_MAX
-from repro.core.pool import PoolMeta
+from repro.core.pool import PoolMeta, keys_i64
 from repro.core.repartition import node_key_ranges
 
 
@@ -55,7 +55,7 @@ def leaf_ranges(state: DexState, meta: PoolMeta):
     (the children-graph walk of :func:`node_key_ranges` keeps working after
     on-mesh splits relocate leaves into free-list headroom)."""
     gids, lo, hi, lvl = node_key_ranges(
-        np.asarray(state.pool.pool_keys), meta,
+        keys_i64(state.pool), meta,
         np.asarray(state.pool.pool_children), with_levels=True,
     )
     keep = lvl == 0

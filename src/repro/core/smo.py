@@ -65,11 +65,14 @@ from repro.core.nodes import FANOUT, KEY_MAX, NULL
 from repro.core.pool import (
     PoolMeta,
     SepPlanes,
-    SubtreePool,
     blocks,
     compress_rows,
+    join_words,
+    keys_i64,
     node_rows,
+    pool_specs,
     set_node_rows,
+    split_words,
     top_walk,
 )
 from repro.core.write import (
@@ -191,8 +194,10 @@ def make_dex_smo(
         winner = jnp.concatenate([diff, jnp.ones((1,), bool)]) & live_s
         upd_w = winner & exists[order]
         ust = jnp.where(upd_w, st_s, s_local)                   # OOB drop
-        new_pv = pool.pool_values.at[ust, lo_s * FANOUT + uslot[order]].set(
-            v_s, mode="drop"
+        new_pv = jax.tree.map(
+            lambda w, v: w.at[ust, lo_s * FANOUT + uslot[order]].set(
+                v, mode="drop"),
+            pool.pool_values, split_words(v_s),
         )
 
         # --- 4. per-leaf staging of fresh inserts -------------------------
@@ -353,10 +358,11 @@ def make_dex_smo(
         # --- 8. dense inner pass: split full parents toward the root ------
         n_inner_splits = jnp.int64(0)
         if levels >= 3:
-            # whole-block passes work on the [S, C, F] node view
-            out_pk, out_pc, out_pv = (
-                blocks(x) for x in (out_pk, out_pc, out_pv)
-            )
+            # whole-block passes work on the [S, C, F] node view, the keys
+            # as int64 (the pass compares and moves them over whole blocks
+            # anyway) and the values as words (it only clears sibling rows)
+            out_pk = blocks(join_words(out_pk))
+            out_pc, out_pv = blocks(out_pc), jax.tree.map(blocks, out_pv)
             flagged0 = need_split & ~parent_room & (m_seg > 0)
             f_st = jnp.where(flagged0, seg_st, s_local)
             flag = (
@@ -419,9 +425,9 @@ def make_dex_smo(
                 out_occ = out_occ.at[row_ix, sib_safe].set(
                     m_g - left_n, mode="drop"
                 )
-                out_pv = out_pv.at[row_ix, sib_safe].set(
-                    jnp.zeros((s_local, cap_nodes, FANOUT), jnp.int64),
-                    mode="drop",
+                out_pv = jax.tree.map(
+                    lambda w: w.at[row_ix, sib_safe].set(0, mode="drop"),
+                    out_pv,
                 )
                 alloc_g = alloc_g + jnp.sum(ok.astype(jnp.int32), axis=1)
                 # single separator insert into each winner's parent row
@@ -476,9 +482,9 @@ def make_dex_smo(
                     .at[row_ix, nf_par].set(True, mode="drop")
                 )
             new_alloc = alloc_g
-            out_pk, out_pc, out_pv = (
-                x.reshape(s_local, -1) for x in (out_pk, out_pc, out_pv)
-            )
+            out_pk = split_words(out_pk.reshape(s_local, -1))
+            out_pc = out_pc.reshape(s_local, -1)
+            out_pv = jax.tree.map(lambda w: w.reshape(s_local, -1), out_pv)
 
         # --- 9. statuses back to the requesting lanes ---------------------
         outcome_w = jnp.where(
@@ -526,21 +532,17 @@ def make_dex_smo(
                 new_succ, new_stats, out_status)
 
     dev = P(cfg.all_axes)
-    pool_specs = SubtreePool(
-        top_keys=P(),
-        top_children=P(),
-        pool_keys=P(cfg.memory_axis),
-        pool_children=P(cfg.memory_axis),
-        pool_values=P(cfg.memory_axis),
-    )
+    pool_in = pool_specs(cfg.memory_axis)
     mem = P(cfg.memory_axis)
+    words = pool_in.pool_keys
 
     sharded = routing.shard_map_compat(
         local_fn,
         mesh=mesh,
-        in_specs=(pool_specs, mem, mem, dev, dev, dev,
+        in_specs=(pool_in, mem, mem, dev, dev, dev,
                   P(cfg.all_axes), P(cfg.all_axes)),
-        out_specs=(mem, mem, mem, mem, mem, dev, dev, dev, P(cfg.all_axes)),
+        out_specs=(words, mem, words, mem, mem, dev, dev, dev,
+                   P(cfg.all_axes)),
     )
 
     def smo(state: DexState, keys: jax.Array, values: jax.Array):
@@ -722,7 +724,7 @@ def refresh_sep_planes(
     cap = meta.subtree_cap
     s_idx = changed // cap
     l_idx = changed % cap
-    pk = blocks(np.asarray(state.pool.pool_keys))
+    pk = blocks(keys_i64(state.pool))
     prefix = np.asarray(sep.prefix).copy()
     nbits = np.asarray(sep.nbits).copy()
     suffix = np.asarray(sep.suffix).copy()

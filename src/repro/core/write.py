@@ -70,7 +70,13 @@ from repro.core.dex import (
     init_state,
 )
 from repro.core.nodes import FANOUT, KEY_MAX
-from repro.core.pool import PoolMeta, build_pool, node_rows, set_node_rows
+from repro.core.pool import (
+    PoolMeta,
+    Words,
+    build_pool,
+    node_rows,
+    set_node_rows,
+)
 from repro.kernels.leaf_write import leaf_write
 from repro.kernels.ref import leaf_write_ref
 
@@ -90,8 +96,8 @@ def _seg_positions(mask: jax.Array, new_seg: jax.Array) -> jax.Array:
 
 
 def _apply_leaf_writes(
-    pool_keys: jax.Array,    # [S_local, C * F] this memory column's shard
-    pool_values: jax.Array,  # [S_local, C * F]
+    pool_keys: Words,        # [S_local, C * F] word planes: this memory
+    pool_values: Words,      # [S_local, C * F]  column's shard
     occupancy: jax.Array,    # [S_local, C]
     meta: PoolMeta,
     cfg: DexMeshConfig,
@@ -206,7 +212,7 @@ def _apply_leaf_writes(
     seg_active = (
         jnp.zeros((n,), bool).at[seg_id].max(upd_apply | ins_apply)
     )
-    w_st = jnp.where(seg_active, seg_st, pool_keys.shape[0])  # OOB drop
+    w_st = jnp.where(seg_active, seg_st, occupancy.shape[0])  # OOB drop
     out_pk = set_node_rows(pool_keys, w_st, seg_lo, new_k)
     out_pv = set_node_rows(pool_values, w_st, seg_lo, new_v)
     out_occ = occupancy.at[w_st, seg_lo].set(new_occ, mode="drop")

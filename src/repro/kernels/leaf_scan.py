@@ -34,6 +34,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.nodes import KEY_MAX
+from repro.core.pool import join_words, split_words
 from repro.kernels import use_interpret
 from repro.kernels.leaf_write import (
     _KMAX_HI,
@@ -41,11 +42,9 @@ from repro.kernels.leaf_write import (
     _ZERO,
     _count,
     _exclusive_prefix_count,
-    _join_i64,
     _live,
     _lt_planes,
     _row_block,
-    _split_i64,
 )
 
 BLOCK_B = 8
@@ -125,9 +124,9 @@ def leaf_scan(
         counts = jnp.pad(counts, (0, pad))
     bp = window_keys.shape[0]
 
-    khi, klo = _split_i64(window_keys)
-    vhi, vlo = _split_i64(window_values)
-    shi, slo = _split_i64(start_keys.astype(jnp.int64))
+    khi, klo = split_words(window_keys)
+    vhi, vlo = split_words(window_values)
+    shi, slo = split_words(start_keys.astype(jnp.int64))
 
     grid = (bp // block_b,)
     row = pl.BlockSpec((block_b, w), _row_block)
@@ -148,6 +147,6 @@ def leaf_scan(
         interpret=use_interpret() if interpret is None else interpret,
         name="leaf_scan",
     )(khi, klo, vhi, vlo, shi[:, None], slo[:, None], counts[:, None])
-    out_k = _join_i64(okhi, oklo)
-    out_v = _join_i64(ovhi, ovlo)
+    out_k = join_words((okhi, oklo))
+    out_v = join_words((ovhi, ovlo))
     return out_k[:b], out_v[:b], taken[:b, 0]

@@ -40,6 +40,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.nodes import KEY_MAX
+from repro.core.pool import join_words, split_words
 from repro.kernels import use_interpret
 from repro.kernels.leaf_write import (
     _KMAX_HI,
@@ -48,11 +49,9 @@ from repro.kernels.leaf_write import (
     _any,
     _count,
     _exclusive_prefix_count,
-    _join_i64,
     _live,
     _lt_planes,
     _row_block,
-    _split_i64,
 )
 
 BLOCK_B = 8
@@ -199,10 +198,10 @@ def leaf_split(
         ins_val = jnp.pad(ins_val, ((0, pad), (0, 0)))
     qp = rows_k.shape[0]
 
-    khi, klo = _split_i64(rows_k)
-    vhi, vlo = _split_i64(rows_v)
-    ikh, ikl = _split_i64(ins_key)
-    ivh, ivl = _split_i64(ins_val)
+    khi, klo = split_words(rows_k)
+    vhi, vlo = split_words(rows_v)
+    ikh, ikl = split_words(ins_key)
+    ivh, ivl = split_words(ins_val)
 
     grid = (qp // block_b,)
     row = pl.BlockSpec((block_b, f), _row_block)
@@ -221,12 +220,12 @@ def leaf_split(
     )(khi, klo, vhi, vlo, ikh, ikl, ivh, ivl)
     lkh, lkl, lvh, lvl, rkh, rkl, rvh, rvl, occl, occr, sh, sl, did = outs
     return (
-        _join_i64(lkh, lkl)[:q],
-        _join_i64(lvh, lvl)[:q],
-        _join_i64(rkh, rkl)[:q],
-        _join_i64(rvh, rvl)[:q],
+        join_words((lkh, lkl))[:q],
+        join_words((lvh, lvl))[:q],
+        join_words((rkh, rkl))[:q],
+        join_words((rvh, rvl))[:q],
         occl[:q, 0],
         occr[:q, 0],
-        _join_i64(sh, sl)[:q, 0],
+        join_words((sh, sl))[:q, 0],
         did[:q, 0],
     )

@@ -20,7 +20,8 @@ the row has enough slack (overflowing leaves are shed *before* the kernel —
 the host SMO path replays them).  Staged updates target distinct slots.
 
 int64 keys/values travel as (hi, lo) int32 planes like kernels/leaf_scan.py
-(the TPU VPU has no native 64-bit lanes).  Inside the kernel body every
+(the TPU VPU has no native 64-bit lanes), in the pool's word convention
+(``core/pool.split_words``).  Inside the kernel body every
 value is 32-bit — iotas, literals, index maps and reductions are spelled
 int32 so the package-wide x64 flag cannot reach Mosaic — per-lane scalars
 travel as [B, 1] columns, and bool masks are never reshaped (Mosaic cannot);
@@ -39,6 +40,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.nodes import KEY_MAX
+from repro.core.pool import join_words, split_words
 from repro.kernels import use_interpret
 
 BLOCK_B = 8
@@ -47,17 +49,6 @@ BLOCK_B = 8
 _KMAX_HI = np.int32(0x7FFFFFFF)
 _KMAX_LO = np.int32(-1)
 _ZERO = np.int32(0)
-
-
-def _split_i64(x: jax.Array):
-    """int64 -> (hi int32, lo uint32-as-int32) planes."""
-    hi = (x >> 32).astype(jnp.int32)
-    lo = (x & jnp.int64(0xFFFFFFFF)).astype(jnp.uint32).astype(jnp.int32)
-    return hi, lo
-
-
-def _join_i64(hi: jax.Array, lo: jax.Array) -> jax.Array:
-    return (hi.astype(jnp.int64) << 32) | lo.astype(jnp.uint32).astype(jnp.int64)
 
 
 def _row_block(i):
@@ -206,11 +197,11 @@ def leaf_write(
         ins_val = jnp.pad(ins_val, ((0, pad), (0, 0)))
     qp = rows_k.shape[0]
 
-    khi, klo = _split_i64(rows_k)
-    vhi, vlo = _split_i64(rows_v)
-    uvh, uvl = _split_i64(upd_val)
-    ikh, ikl = _split_i64(ins_key)
-    ivh, ivl = _split_i64(ins_val)
+    khi, klo = split_words(rows_k)
+    vhi, vlo = split_words(rows_v)
+    uvh, uvl = split_words(upd_val)
+    ikh, ikl = split_words(ins_key)
+    ivh, ivl = split_words(ins_val)
 
     grid = (qp // block_b,)
     row = pl.BlockSpec((block_b, f), _row_block)
@@ -234,6 +225,6 @@ def leaf_write(
         name="leaf_write",
     )(khi, klo, vhi, vlo, upd_slot.astype(jnp.int32), uvh, uvl,
       ikh, ikl, ivh, ivl)
-    out_k = _join_i64(okh, okl)
-    out_v = _join_i64(ovh, ovl)
+    out_k = join_words((okh, okl))
+    out_v = join_words((ovh, ovl))
     return out_k[:q], out_v[:q], occ[:q, 0]

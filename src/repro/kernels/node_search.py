@@ -20,17 +20,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.nodes import FANOUT
-from repro.core.pool import SEP_SUFFIX_SENTINEL
+from repro.core.pool import SEP_SUFFIX_SENTINEL, split_words
 from repro.kernels import use_interpret
 
 BLOCK_B = 256
-
-
-def _split_i64(x: jax.Array):
-    """int64 -> (hi int32, lo uint32-as-int32) planes."""
-    hi = (x >> 32).astype(jnp.int32)
-    lo = (x & jnp.int64(0xFFFFFFFF)).astype(jnp.uint32).astype(jnp.int32)
-    return hi, lo
 
 
 def _leq_planes(khi, klo, qhi, qlo):
@@ -79,9 +72,9 @@ def node_search(
         queries = jnp.pad(queries, (0, pad), constant_values=-1)
     bp = node_keys.shape[0]
 
-    khi, klo = _split_i64(node_keys)
-    qhi, qlo = _split_i64(queries)
-    vhi, vlo = _split_i64(node_values)
+    khi, klo = split_words(node_keys)
+    qhi, qlo = split_words(queries)
+    vhi, vlo = split_words(node_values)
     vplanes = jnp.stack([vhi, vlo], axis=-1)  # [B, F, 2]
 
     grid = (bp // block_b,)
@@ -190,9 +183,9 @@ def node_search_prefix(
         queries = jnp.pad(queries, (0, pad), constant_values=-1)
     bp = prefix.shape[0]
 
-    phi, plo = _split_i64(prefix)
-    khi, klo = _split_i64(node_keys)
-    qhi, qlo = _split_i64(queries)
+    phi, plo = split_words(prefix)
+    khi, klo = split_words(node_keys)
+    qhi, qlo = split_words(queries)
 
     grid = (bp // block_b,)
     slot = pl.pallas_call(

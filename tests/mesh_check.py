@@ -734,7 +734,7 @@ def main() -> None:
     # poison every cached copy of a moved node on every chip: if the
     # version bump failed to invalidate them, lookups would serve garbage
     gids_all, lo_all, hi_all = node_key_ranges(
-        np.asarray(state.pool.pool_keys), meta,
+        pool_mod.keys_i64(state.pool), meta,
         np.asarray(state.pool.pool_children),
     )
     affected = np.zeros(gids_all.shape, bool)

@@ -10,6 +10,7 @@ import pathlib
 import subprocess
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -41,7 +42,8 @@ class TestSubtreePool:
         dev, meta_d = pool_mod.build_pool(keys, keys * 3, level_m=1,
                                           n_shards=2)
         assert meta_h == meta_d
-        for h, d in zip(host, dev):
+        for h, d in zip(jax.tree.leaves(host), jax.tree.leaves(dev),
+                        strict=True):
             assert isinstance(h, np.ndarray)
             np.testing.assert_array_equal(h, np.asarray(d))
 
@@ -61,9 +63,9 @@ class TestSubtreePool:
         q0 = keys[:256][st == 0]
         if q0.size:
             f, v = pool_mod.subtree_walk_ref(
-                pool.pool_keys[0],
+                pool_mod.keys_i64(pool)[0],
                 pool.pool_children[0],
-                pool.pool_values[0],
+                pool_mod.values_i64(pool)[0],
                 q0,
                 levels=meta.levels_in_subtree,
             )

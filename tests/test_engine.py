@@ -568,8 +568,8 @@ class TestFleetCachePolicyGoldens:
         stats = np.asarray(state.stats)
         return {
             "results": res_h.hexdigest()[:16],
-            "state": _digest(pool_mod.blocks(state.pool.pool_keys),
-                             pool_mod.blocks(state.pool.pool_values),
+            "state": _digest(pool_mod.blocks(pool_mod.keys_i64(state.pool)),
+                             pool_mod.blocks(pool_mod.values_i64(state.pool)),
                              state.versions, state.occupancy),
             "stats12": _digest(stats[:, :12]),
         }, stats
@@ -622,8 +622,8 @@ class TestFleetCachePolicyGoldens:
                          .encode())
         got = {
             "results": res_h.hexdigest()[:16],
-            "state": _digest(pool_mod.blocks(s_pipe.pool.pool_keys),
-                             pool_mod.blocks(s_pipe.pool.pool_values),
+            "state": _digest(pool_mod.blocks(pool_mod.keys_i64(s_pipe.pool)),
+                             pool_mod.blocks(pool_mod.values_i64(s_pipe.pool)),
                              s_pipe.versions, s_pipe.occupancy),
             "stats12": _digest(np.asarray(s_pipe.stats)[:, :12]),
         }

@@ -70,17 +70,18 @@ class TestSubtreeWalk:
         st = np.asarray(pool_mod.top_walk(pool, meta, jnp.asarray(q)))
         s0 = int(st[0])
         qs = q[st == s0]
+        pk, pv = pool_mod.keys_i64(pool), pool_mod.values_i64(pool)
         f_k, v_k = ops.subtree_walk(
-            pool.pool_keys[s0],
+            pk[s0],
             pool.pool_children[s0],
-            pool.pool_values[s0],
+            pv[s0],
             qs,
             levels=meta.levels_in_subtree,
         )
         f_r, v_r = ref.subtree_walk_ref(
-            pool.pool_keys[s0],
+            pk[s0],
             pool.pool_children[s0],
-            pool.pool_values[s0],
+            pv[s0],
             qs,
             levels=meta.levels_in_subtree,
         )
@@ -94,7 +95,8 @@ class TestSubtreeWalk:
         pool, meta = pool_mod.build_pool(keys, keys, level_m=1)
         q = keys[:5]
         f, v = ops.subtree_walk(
-            pool.pool_keys[0], pool.pool_children[0], pool.pool_values[0],
+            pool_mod.keys_i64(pool)[0], pool.pool_children[0],
+            pool_mod.values_i64(pool)[0],
             q, levels=meta.levels_in_subtree,
         )
         st = np.asarray(pool_mod.top_walk(pool, meta, jnp.asarray(q)))

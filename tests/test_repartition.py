@@ -102,7 +102,7 @@ def _small_state(n_route=2, n_memory=1, n_keys=2000):
 class TestNodeKeyRanges:
     def test_ranges_tile_each_level(self):
         keys, pool, meta, _, _, _ = _small_state()
-        gids, lo, hi = node_key_ranges(np.asarray(pool.pool_keys), meta)
+        gids, lo, hi = node_key_ranges(pool_mod.keys_i64(pool), meta)
         assert (hi.astype(object) > lo.astype(object)).all()
         # leaves alone must tile [KEY_MIN, KEY_MAX) exactly once
         is_leaf = (gids % meta.subtree_cap) >= meta.leaf_start
@@ -113,7 +113,7 @@ class TestNodeKeyRanges:
 
     def test_every_key_covered_by_one_leaf(self):
         keys, pool, meta, _, _, _ = _small_state()
-        gids, lo, hi = node_key_ranges(np.asarray(pool.pool_keys), meta)
+        gids, lo, hi = node_key_ranges(pool_mod.keys_i64(pool), meta)
         is_leaf = (gids % meta.subtree_cap) >= meta.leaf_start
         lo_l, hi_l = lo[is_leaf], hi[is_leaf]
         probe = keys[:: 97]
@@ -153,7 +153,7 @@ class TestInstallBoundaries:
             np.asarray(st2.boundaries), new.boundaries
         )
         # nodes outside the moved interval keep version 0
-        gids, lo, hi = node_key_ranges(np.asarray(pool.pool_keys), meta)
+        gids, lo, hi = node_key_ranges(pool_mod.keys_i64(pool), meta)
         (a, b), = moved_intervals(old, new)
         untouched = gids[(hi.astype(object) <= a) | (lo.astype(object) >= b)]
         assert (v[0, untouched] == 0).all()

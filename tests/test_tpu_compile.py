@@ -8,6 +8,8 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -19,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import dex as dex_mod
 from repro.core import engine as engine_mod
 from repro.core import pool as pool_mod
+from repro.core import smo as smo_mod
 from repro.core.nodes import FANOUT, KEY_MAX, KEY_MIN
 from repro.kernels.leaf_scan import leaf_scan
 from repro.kernels.leaf_split import leaf_split
@@ -103,8 +106,9 @@ def test_mesh_helper_on_described_2x2(topo):
     assert dict(one.shape) == {"data": 1, "model": 1}
 
 
-def test_engine_step_compiles_with_kernels(topo):
-    """The jitted 1x1 engine step, all four opcodes, kernels compiled in."""
+def _one_chip_state(topo):
+    """A 1x1 mesh on the described chip and the shapes of a DexState over
+    a 20,000-key pool on it."""
     keys = np.arange(1, 20_001, dtype=np.int64) * 4
     pool, meta = pool_mod.build_pool(keys, keys * 7, level_m=1, fill=0.7)
     mesh = dex_mod.make_dex_mesh(topo.devices[:1])
@@ -117,7 +121,27 @@ def test_engine_step_compiles_with_kernels(topo):
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
         state, dex_mod.state_shardings(mesh, cfg),
     )
-    lanes = NamedSharding(mesh, P(cfg.all_axes))
+    return state, meta, cfg, mesh, NamedSharding(mesh, P(cfg.all_axes))
+
+
+def _kernels(hlo):
+    """Names of the program's Pallas kernels: the instruction names of its
+    TPU custom calls (the trace reduction finds the kernels by them)."""
+    return set(re.findall(r"%(\w+?)(?:\.\d+)? = .*custom-call\(.*"
+                          r"tpu_custom_call", hlo))
+
+
+def _assert_no_int64_pool_plane(hlo, meta):
+    """The pool's key and value planes are int32 words: the program holds
+    no int64 array of a plane's shape (the compiler's X64 split and combine
+    of a whole plane would make one)."""
+    plane = f"s64[{meta.n_subtrees_padded},{meta.subtree_cap * FANOUT}]"
+    assert plane not in hlo, f"{hlo.count(plane)} x {plane}"
+
+
+def test_engine_step_compiles_with_kernels(topo):
+    """The jitted 1x1 engine step, all four opcodes, kernels compiled in."""
+    state, meta, cfg, mesh, lanes = _one_chip_state(topo)
     step = jax.jit(engine_mod.make_dex_engine(
         meta, cfg, mesh, max_count=MAX_COUNT, interpret=False
     ), donate_argnums=0)
@@ -128,3 +152,23 @@ def test_engine_step_compiles_with_kernels(topo):
         _shape(lanes, (1024,), jnp.int64),
     ).compile()
     _assert_kernel(compiled)
+    hlo = compiled.as_text()
+    assert _kernels(hlo) == {"leaf_scan", "leaf_write"}
+    _assert_no_int64_pool_plane(hlo, meta)
+
+
+def test_smo_round_compiles_with_kernels(topo):
+    """The jitted 1x1 SMO round: ``leaf_split`` compiled in, and no int64
+    pool plane either."""
+    state, meta, cfg, mesh, lanes = _one_chip_state(topo)
+    rnd = jax.jit(smo_mod.make_dex_smo(meta, cfg, mesh, interpret=False),
+                  donate_argnums=0)
+    compiled = rnd.lower(
+        state,
+        _shape(lanes, (1024,), jnp.int64),
+        _shape(lanes, (1024,), jnp.int64),
+    ).compile()
+    _assert_kernel(compiled)
+    hlo = compiled.as_text()
+    assert _kernels(hlo) == {"leaf_split", "leaf_write"}
+    _assert_no_int64_pool_plane(hlo, meta)
